@@ -104,11 +104,11 @@ def run_kmeans_perf(quick: bool = True, sizes=None):
     changes."""
     sizes = sizes or ([30_000, 100_000] if quick else [100_000, 1_000_000])
     d, k, iters = 16, 16, 5
-    from repro.kernels.padding import INTERPRET
-    # NOTE: with INTERPRET=1 (CPU container) the pallas variants run the
-    # Pallas *emulator* and their wall-clock is meaningless as a TPU proxy;
-    # the ref-vs-ref rows isolate the one-hot -> fused algorithmic change,
-    # the pallas rows become meaningful with REPRO_PALLAS_INTERPRET=0.
+    from repro.kernels.padding import interpret
+    # NOTE: on a CPU backend the pallas variants run the Pallas
+    # *emulator* and their wall-clock is meaningless as a TPU proxy; the
+    # ref-vs-ref rows isolate the one-hot -> fused algorithmic change,
+    # the pallas rows become meaningful on a TPU.
     rows = []
     variants = [
         ("onehot-ref", _fit_onehot, "ref"),          # seed baseline
@@ -127,7 +127,7 @@ def run_kmeans_perf(quick: bool = True, sizes=None):
             rows.append(dict(n=n, d=d, k=k, iters=iters, variant=name,
                              seconds=fmt(secs, 4),
                              speedup_vs_onehot_ref=fmt(base / secs, 2),
-                             pallas_interpret=int(INTERPRET)))
+                             pallas_interpret=int(interpret())))
     emit(rows, "fig6_kmeans_perf")
 
 

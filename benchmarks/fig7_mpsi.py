@@ -96,11 +96,11 @@ def run_psi_engine_perf(quick: bool = True, sizes=None):
 
     Variants: the per-element host loop (seed baseline), the vectorized
     jnp ref path (PRF + lax.sort + bitonic merge — the algorithmic win,
-    meaningful on CPU), and the Pallas kernel path (meaningful with
-    REPRO_PALLAS_INTERPRET=0 on real TPU; under the interpreter its
+    meaningful on CPU), and the Pallas kernel path (meaningful on a TPU;
+    on a CPU backend the kernels run in interpret mode and its
     wall-clock is emulator overhead, as in fig6's kmeans engine rows).
     """
-    from repro.kernels.padding import INTERPRET
+    from repro.kernels.padding import interpret
     from repro.kernels.sorted_intersect.kernel import SINGLE_PASS_MAX_P
     from repro.kernels.sorted_intersect.ops import next_pow2
     from repro.psi import engine as psi_engine
@@ -108,7 +108,7 @@ def run_psi_engine_perf(quick: bool = True, sizes=None):
     sizes = sizes or ([20_000, 100_000] if quick else
                       [100_000, 300_000, 1_000_000])
     variants = [("host-loop", None), ("engine-ref", "ref")]
-    if not INTERPRET or quick:
+    if not interpret() or quick:
         variants.append(("engine-pallas", "pallas"))
     rows = []
     rng = np.random.default_rng(0)
@@ -143,7 +143,7 @@ def run_psi_engine_perf(quick: bool = True, sizes=None):
                 seconds=fmt(secs, 4),
                 melem_per_s=fmt(2 * n / secs / 1e6, 2),
                 speedup_vs_host=fmt(base / secs, 2),
-                pallas_interpret=int(INTERPRET),
+                pallas_interpret=int(interpret()),
                 merge_tiled=int(tiled)))
     emit(rows, "fig7_psi_engine")
 

@@ -36,6 +36,8 @@ def main() -> None:
              "--census-csv", "experiments/bench/static_census.csv"]))
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (beyond_minibatch, fig6_coreset,
                             fig7_delta_psi, fig7_mpsi, fig45_ablation,

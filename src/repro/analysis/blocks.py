@@ -24,8 +24,7 @@ import dataclasses
 from typing import Dict, List
 
 from repro.kernels.padding import GATHER_VMEM_BUDGET, round_up
-from repro.kernels.sorted_intersect.kernel import (PALLAS_MAX_P,
-                                                   SINGLE_PASS_MAX_P)
+from repro.kernels.sorted_intersect.kernel import SINGLE_PASS_MAX_P
 
 VMEM_BUDGET = 16 * 2 ** 20          # bytes of VMEM per TensorCore
 F32 = 4
@@ -136,28 +135,25 @@ def psi_prf_blocks(p: int, block_n: int = 2048) -> BlockReport:
                        VMEM_BUDGET)
 
 
-SINGLE_PASS_CEILING = VMEM_BUDGET // (U32 * 12)   # 48 bytes per element
-
-
 def sorted_intersect_blocks(p: int,
                             max_p: int = SINGLE_PASS_MAX_P) -> BlockReport:
-    """Bitonic merge.  Single-pass (P ≤ SINGLE_PASS_MAX_P): one block
-    holds 4×(P,) in + 4×(2P,) out u32 lanes → 48 bytes/element, so the
-    exact 16 MB ceiling is ``SINGLE_PASS_CEILING`` ≈ 2^18.4 and the ops
-    wrapper admits only up to the next power of two BELOW it
-    (``SINGLE_PASS_MAX_P`` = 2^18 — the over-admission band this table
-    used to flag is retired).  Past that the ops wrapper re-routes to
-    the multi-pass tiled merge, whose largest block is the local-stage
-    (1, chunk) tile: 2 in + 2 out lanes of ``chunk = 2·PALLAS_MAX_P``
-    elements (PALLAS_MAX_P stays the tiled chunk SPAN — the local pass
-    names half the lanes of the single-pass kernel, so the same budget
-    reaches chunks twice as long)."""
+    """Bitonic merge.  Unlike the other kernels, this count includes the
+    pipeline's second buffer: the merge blocks are the whole lane pair
+    (or a whole chunk of it), so the two copies the v5e compiler
+    allocates are what decides the bound.  Single pass (P ≤
+    SINGLE_PASS_MAX_P): 2 in + 2 out (2P/128, 128) u32 blocks, twice →
+    64 bytes per per-side element, which fills the 16 MB budget exactly
+    at 2^18 (the compiler refuses 2^19: "Scoped allocation with size
+    32.00M and limit 16.00M").  Past it the ops wrapper routes to the
+    multi-pass tiled merge, whose largest block is the local pass over
+    ``chunk = 2·SINGLE_PASS_MAX_P`` elements — the same four lanes, so
+    the same bound."""
     if p > max_p:
-        chunk = min(2 * PALLAS_MAX_P, 2 * p)
-        resident = U32 * 4 * chunk
+        chunk = min(2 * SINGLE_PASS_MAX_P, 2 * p)
+        resident = 2 * U32 * 4 * chunk
         note = f"tiled multi-pass merge (chunk={chunk})"
     else:
-        resident = U32 * (4 * p + 4 * 2 * p)
+        resident = 2 * U32 * 4 * 2 * p
         note = ""
     return BlockReport("sorted_intersect", f"P={p}", resident,
                        VMEM_BUDGET, note=note)
@@ -168,7 +164,7 @@ def vmem_report(shapes: Dict[str, Dict[str, int]] = None
     """The default block-check matrix: every Pallas kernel at its
     engine-typical shapes plus the largest shape that must still fit
     (the gather kernels exactly AT the budget boundary, the merge at
-    PALLAS_MAX_P)."""
+    SINGLE_PASS_MAX_P)."""
     budget_rows = GATHER_VMEM_BUDGET // (F32 * 128)   # N at d_pad=128
     i8_rows = GATHER_VMEM_BUDGET // (I8 * 128)        # 4x the f32 reach
     reports = [

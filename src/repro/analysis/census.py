@@ -37,6 +37,7 @@ import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.extend.core import ClosedJaxpr
 import numpy as np
 
 # collective primitive -> canonical census name (jaxpr spelling)
@@ -124,7 +125,7 @@ def _sub_jaxprs(params: Dict[str, Any]):
     for val in params.values():
         items = val if isinstance(val, (tuple, list)) else (val,)
         for item in items:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
             elif hasattr(item, "eqns"):
                 yield item

@@ -76,7 +76,6 @@ def _ensure_virtual_devices() -> None:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 
 def available_meshes() -> Dict[str, Any]:

@@ -15,7 +15,8 @@
   ssd_scan        — Mamba2 SSD chunked scan with VMEM-carried state
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-public wrapper, padding + layout), ref.py (pure-jnp oracle). Kernels run
-interpret=True on CPU (this container); set REPRO_PALLAS_INTERPRET=0 on
-real TPU hardware.
+public wrapper, padding + layout), ref.py (pure-jnp oracle). The ops
+wrappers run the kernels in interpret mode exactly when JAX's backend is
+the CPU (``padding.interpret``, decided at trace time); on a TPU they
+always compile with Mosaic.
 """

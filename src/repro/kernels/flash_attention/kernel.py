@@ -100,7 +100,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,  # 
                            prefix: int = 0, logit_cap: float = 0.0,
                            block_q: int = 512, block_k: int = 512,
                            sq_real: int, sk_real: int, d_real: int,
-                           interpret: bool = True):
+                           interpret: bool):
     """q (BKV, G, Sq, D), k/v (BKV, Sk, D) — padded so Sq % block_q == 0,
     Sk % block_k == 0, D % 128 == 0. Returns (BKV, G, Sq, D) f32.
 

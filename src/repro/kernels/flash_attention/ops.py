@@ -2,20 +2,18 @@
 
 Accepts framework-layout tensors q (B,Sq,H,Dh), k/v (B,Sk,KV,Dh); folds
 GQA groups, pads Sq/Sk to the block size and Dh to 128, runs the kernel,
-and restores layout. interpret=True on CPU (REPRO_PALLAS_INTERPRET=0 on
-real TPU).
+and restores layout. Interpret mode exactly on a CPU backend
+(``padding.interpret``).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+from repro.kernels.padding import interpret
 
 
 def _round_up(x: int, m: int) -> int:
@@ -50,7 +48,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     out = flash_attention_pallas(
         qf, kf, vf, causal=causal, window=window, prefix=prefix,
         logit_cap=logit_cap, block_q=bq, block_k=bk,
-        sq_real=sq, sk_real=sk, d_real=dh, interpret=INTERPRET)
+        sq_real=sq, sk_real=sk, d_real=dh, interpret=interpret())
 
     out = out.reshape(b, kv, g, sqp, dp).transpose(0, 3, 1, 2, 4)
     out = out.reshape(b, sqp, h, dp)[:, :sq, :, :dh]

@@ -32,13 +32,16 @@ def _assign_kernel(k_real: int, points_ref, cents_ref, assign_ref, dist_ref):
     # clamp BEFORE the argmin (matching the ref oracle): cancellation can
     # leave tiny negatives whose ordering would otherwise flip ties
     d2 = jnp.where(col < k_real, jnp.maximum(d2, 0.0), MASK_LARGE)
-    assign_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    dist_ref[...] = jnp.min(d2, axis=1)
+    # per-row results as one (1, BN) lane row: a 2-D block stays on the
+    # (8, 128) tiling rule when the call is vmapped (the batch dim is
+    # squeezed in front of it)
+    assign_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)[None]
+    dist_ref[...] = jnp.min(d2, axis=1)[None]
 
 
 def kmeans_assign_pallas(points: jnp.ndarray, centroids: jnp.ndarray, *,
                          k_real: int, block_n: int = 1024,
-                         interpret: bool = True):
+                         interpret: bool):
     """points (Np, dp) f32 (padded), centroids (Kp, dp) f32 (padded).
 
     Np % block_n == 0; dp % 128 == 0; Kp % 128 == 0. Returns
@@ -49,20 +52,22 @@ def kmeans_assign_pallas(points: jnp.ndarray, centroids: jnp.ndarray, *,
     assert n % block_n == 0 and d % 128 == 0 and kp % 128 == 0, (n, d, kp)
     grid = (n // block_n,)
     kernel = functools.partial(_assign_kernel, k_real)
-    return pl.pallas_call(
+    assign, dist = pl.pallas_call(
         kernel,
+        name="kmeans_assign",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),   # point tile
             pl.BlockSpec((kp, d), lambda i: (0, 0)),        # all centroids
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
         interpret=interpret,
     )(points, centroids)
+    return assign[0], dist[0]

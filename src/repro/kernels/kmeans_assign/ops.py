@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.kmeans_assign.kernel import kmeans_assign_pallas
-from repro.kernels.padding import INTERPRET, pad_points_centroids
+from repro.kernels.padding import (interpret, pad_points_centroids,
+                                   record_path)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n",))
@@ -22,6 +23,7 @@ def kmeans_assign(points: jnp.ndarray, centroids: jnp.ndarray, *,
     n, d = points.shape
     k = centroids.shape[0]
     p, c, bn = pad_points_centroids(points, centroids, block_n)
+    record_path("kmeans_assign", "pallas")
     assign, dist = kmeans_assign_pallas(p, c, k_real=k, block_n=bn,
-                                        interpret=INTERPRET)
+                                        interpret=interpret())
     return assign[:n], dist[:n]

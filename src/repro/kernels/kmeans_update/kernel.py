@@ -79,8 +79,8 @@ def _update_kernel(k_real: int, n_real: int, block_n: int,
     valid_row = jnp.where(row < n_real, row, -1)
     assign, dist, tile_sums, tile_counts = _tile_update(p, c, k_real,
                                                         valid_row)
-    assign_ref[...] = assign
-    dist_ref[...] = dist
+    assign_ref[...] = assign[None]            # (1, BN) lane rows, as in
+    dist_ref[...] = dist[None]                # kmeans_assign
 
     @pl.when(i == 0)
     def _():
@@ -93,7 +93,7 @@ def _update_kernel(k_real: int, n_real: int, block_n: int,
 
 def kmeans_update_pallas(points: jnp.ndarray, centroids: jnp.ndarray, *,
                          k_real: int, n_real: int, block_n: int = 1024,
-                         interpret: bool = True):
+                         interpret: bool):
     """points (Np, dp) f32 (padded), centroids (Kp, dp) f32 (padded).
 
     Np % block_n == 0; dp % 128 == 0; Kp % 128 == 0. Returns
@@ -105,27 +105,29 @@ def kmeans_update_pallas(points: jnp.ndarray, centroids: jnp.ndarray, *,
     assert n % block_n == 0 and d % 128 == 0 and kp % 128 == 0, (n, d, kp)
     grid = (n // block_n,)
     kernel = functools.partial(_update_kernel, k_real, n_real, block_n)
-    return pl.pallas_call(
+    assign, dist, sums, counts = pl.pallas_call(
         kernel,
+        name="kmeans_update",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),   # point tile
             pl.BlockSpec((kp, d), lambda i: (0, 0)),        # all centroids
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((kp, d), lambda i: (0, 0)),        # revisited accum
             pl.BlockSpec((1, kp), lambda i: (0, 0)),        # revisited accum
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
             jax.ShapeDtypeStruct((kp, d), jnp.float32),
             jax.ShapeDtypeStruct((1, kp), jnp.float32),
         ],
         interpret=interpret,
     )(points, centroids)
+    return assign[0], dist[0], sums, counts
 
 
 # ------------------------------------------------- scalar-prefetch gather
@@ -133,24 +135,25 @@ def kmeans_update_pallas(points: jnp.ndarray, centroids: jnp.ndarray, *,
 
 def _update_gather_kernel(k_real: int, b_real: int, block_n: int,
                           idx_ref, points_ref, cents_ref,
-                          assign_ref, dist_ref, sums_ref, counts_ref):
+                          assign_ref, dist_ref, sums_ref, counts_ref,
+                          buf_ref):
     i = pl.program_id(0)
-    dp = points_ref.shape[1]
 
-    def gather_row(r, acc):
+    def gather_row(r, c):
         j = idx_ref[i * block_n + r]              # prefetched batch index
-        row = points_ref[pl.ds(j, 1), :]          # (1, dp) dynamic slice
-        return jax.lax.dynamic_update_slice(acc, row, (r, 0))
+        buf_ref[pl.ds(r, 1), :] = points_ref[pl.ds(j, 1), :]
+        return c
 
-    p = jax.lax.fori_loop(0, block_n, gather_row,
-                          jnp.zeros((block_n, dp), jnp.float32))
+    # rows land in a VMEM scratch tile, one (1, dp) store each
+    jax.lax.fori_loop(0, block_n, gather_row, 0)
+    p = buf_ref[...]                              # (BN, dp) gathered tile
     c = cents_ref[...]                            # (Kp, dp)
     row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
     valid_row = jnp.where(row < b_real, row, -1)  # mask idx-padding slots
     assign, dist, tile_sums, tile_counts = _tile_update(p, c, k_real,
                                                         valid_row)
-    assign_ref[...] = assign
-    dist_ref[...] = dist
+    assign_ref[...] = assign[None]            # (1, BN) lane rows, as in
+    dist_ref[...] = dist[None]                # kmeans_assign
 
     @pl.when(i == 0)
     def _():
@@ -164,7 +167,7 @@ def _update_gather_kernel(k_real: int, b_real: int, block_n: int,
 def kmeans_update_gather_pallas(idx: jnp.ndarray, points: jnp.ndarray,
                                 centroids: jnp.ndarray, *, k_real: int,
                                 b_real: int, block_n: int = 1024,
-                                interpret: bool = True):
+                                interpret: bool):
     """Gather-fused Lloyd update for the mini-batch path: the
     ``points[idx]`` minibatch gather moves INTO the kernel via scalar
     prefetch, so the gathered batch never round-trips through HBM
@@ -191,22 +194,25 @@ def kmeans_update_gather_pallas(idx: jnp.ndarray, points: jnp.ndarray,
             pl.BlockSpec((kp, dp), lambda i, idx_ref: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i, idx_ref: (i,)),
-            pl.BlockSpec((block_n,), lambda i, idx_ref: (i,)),
+            pl.BlockSpec((1, block_n), lambda i, idx_ref: (0, i)),
+            pl.BlockSpec((1, block_n), lambda i, idx_ref: (0, i)),
             pl.BlockSpec((kp, dp), lambda i, idx_ref: (0, 0)),  # revisited
             pl.BlockSpec((1, kp), lambda i, idx_ref: (0, 0)),   # revisited
         ],
+        scratch_shapes=[pltpu.VMEM((block_n, dp), jnp.float32)],
     )
     kernel = functools.partial(_update_gather_kernel, k_real, b_real,
                                block_n)
-    return pl.pallas_call(
+    assign, dist, sums, counts = pl.pallas_call(
         kernel,
+        name="kmeans_update_gather",
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bp,), jnp.int32),
-            jax.ShapeDtypeStruct((bp,), jnp.float32),
+            jax.ShapeDtypeStruct((1, bp), jnp.int32),
+            jax.ShapeDtypeStruct((1, bp), jnp.float32),
             jax.ShapeDtypeStruct((kp, dp), jnp.float32),
             jax.ShapeDtypeStruct((1, kp), jnp.float32),
         ],
         interpret=interpret,
     )(jnp.asarray(idx, jnp.int32), points, centroids)
+    return assign[0], dist[0], sums, counts

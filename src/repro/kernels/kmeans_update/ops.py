@@ -15,9 +15,9 @@ import jax.numpy as jnp
 
 from repro.kernels.kmeans_update.kernel import (kmeans_update_gather_pallas,
                                                 kmeans_update_pallas)
-from repro.kernels.padding import (GATHER_VMEM_BUDGET, INTERPRET,
+from repro.kernels.padding import (GATHER_VMEM_BUDGET, interpret,
                                    pad_gather_idx, pad_points_centroids,
-                                   round_up)
+                                   record_path, round_up)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n",))
@@ -38,18 +38,22 @@ def kmeans_update(points: jnp.ndarray, centroids: jnp.ndarray, *,
     k = centroids.shape[0]
     if idx is None:
         p, c, bn = pad_points_centroids(points, centroids, block_n)
+        record_path("kmeans_update", "dense")
         assign, dist, sums, counts = kmeans_update_pallas(
-            p, c, k_real=k, n_real=n, block_n=bn, interpret=INTERPRET)
+            p, c, k_real=k, n_real=n, block_n=bn,
+            interpret=interpret())
         return assign[:n], dist[:n], sums[:k, :d], counts[0, :k]
     dp = round_up(d, 128)
-    if not INTERPRET and n * dp * 4 > GATHER_VMEM_BUDGET:
+    if not interpret() and n * dp * 4 > GATHER_VMEM_BUDGET:
         # the full point set cannot sit resident in VMEM on real TPU:
         # fall back to gather-then-dense (bitwise-identical values)
         pts = points[idx]
         p, c, bn = pad_points_centroids(pts, centroids, block_n)
         b = idx.shape[0]
+        record_path("kmeans_update", "gather_fallback")
         assign, dist, sums, counts = kmeans_update_pallas(
-            p, c, k_real=k, n_real=b, block_n=bn, interpret=INTERPRET)
+            p, c, k_real=k, n_real=b, block_n=bn,
+            interpret=interpret())
         return assign[:b], dist[:b], sums[:k, :d], counts[0, :k]
     b = idx.shape[0]
     # d/o-only padding: the gather grid tiles idx, not the point rows,
@@ -64,6 +68,8 @@ def kmeans_update(points: jnp.ndarray, centroids: jnp.ndarray, *,
     # same block rule the dense path applies to a B-row batch, so fused
     # and unfused tilings (and therefore outputs) coincide bitwise
     idx_p, bn, _ = pad_gather_idx(idx, block_n, align=128)
+    record_path("kmeans_update", "gather_fused")
     assign, dist, sums, counts = kmeans_update_gather_pallas(
-        idx_p, p, c, k_real=k, b_real=b, block_n=bn, interpret=INTERPRET)
+        idx_p, p, c, k_real=k, b_real=b, block_n=bn,
+        interpret=interpret())
     return assign[:b], dist[:b], sums[:k, :d], counts[0, :k]

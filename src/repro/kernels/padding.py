@@ -10,19 +10,36 @@ diverge between kernels.
 """
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
-# interpret=True on CPU (this container); on real TPU set
-# REPRO_PALLAS_INTERPRET=0 to compile the kernels with Mosaic.
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+from repro.obs.metrics import MetricsRegistry
+
+# Which path each ops wrapper took, counted once per traced program
+# (jit re-uses a traced program without re-counting) as
+# ``<kernel>.<path>``, e.g. ``splitnn_bottom.gather_fallback``: the
+# budget-based fallbacks below report here rather than staying silent.
+KERNEL_PATHS = MetricsRegistry()
+
+
+def record_path(kernel: str, path: str) -> None:
+    KERNEL_PATHS.counter(f"{kernel}.{path}").inc()
+
+
+def interpret() -> bool:
+    """Whether the ops wrappers run their Pallas kernels in interpret
+    mode: exactly when JAX's default backend is the CPU, which has no
+    Mosaic.  Called at trace time, so a TPU process always compiles the
+    kernels with Mosaic and can never fall back to the emulator."""
+    return jax.default_backend() == "cpu"
+
 
 # Resident-block budget for the scalar-prefetch gather kernels on real
 # TPU: a full (N, d_pad) slab past it cannot sit in VMEM, so the ops
 # wrappers fall back to gather-then-dense (bitwise-identical values).
-# Interpret mode has no VMEM — the container always exercises the fused
+# Interpret mode has no VMEM — CPU runs always exercise the fused
 # kernels.
 GATHER_VMEM_BUDGET = 12 * 2**20
 

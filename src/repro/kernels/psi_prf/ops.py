@@ -13,7 +13,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.padding import INTERPRET, round_up
+from repro.kernels.padding import interpret, record_path, round_up
 from repro.kernels.psi_prf import ref
 from repro.kernels.psi_prf.kernel import prf_tags_pallas
 
@@ -36,5 +36,6 @@ def prf_tags(id_hi: jnp.ndarray, id_lo: jnp.ndarray, seed: jnp.ndarray, *,
     np_ = round_up(max(n, 1), bn)
     hi = jnp.zeros((np_,), jnp.uint32).at[:n].set(hi)
     lo = jnp.zeros((np_,), jnp.uint32).at[:n].set(lo)
-    th, tl = prf_tags_pallas(hi, lo, block_n=bn, interpret=INTERPRET)
+    record_path("psi_prf", "pallas")
+    th, tl = prf_tags_pallas(hi, lo, block_n=bn, interpret=interpret())
     return th[:n], tl[:n]

@@ -1,42 +1,50 @@
 """Pallas TPU kernel: sort-merge intersection of two padded sorted
 63-bit key arrays (bitonic merge network).
 
-One grid step holds the two u32 key lanes of both sides resident in
-VMEM and runs the log2(2P) compare-exchange stages of a bitonic MERGE
-(the inputs are already sorted, so the full O(log² n) bitonic sort is
-unnecessary) without touching HBM between stages.  Each stage is a
-reshape + lexicographic min/max over the (kh, kl) lane pair; origin and
-receiver-rank recovery ride on the key's bit 0 and a final cumsum.  The
-merge network IS the jnp ref — ``ref.sorted_intersect`` is pure value
-math, so the kernel body invokes it on the VMEM-resident lanes and the
-two implementations cannot drift; what the pallas_call adds is the
-VMEM residency/layout contract that Mosaic compiles on real TPU
-(parity-tested under INTERPRET).
+The two u32 key lanes of the bitonic sequence C = [A, reverse(B)]
+(built in XLA by ``ref.bitonic_lanes``) are laid out as (rows, 128)
+tiles, element i at (i // 128, i % 128), so every block is Mosaic's
+native (8, 128) u32 tiling.  A compare-exchange stage of stride s pairs
+element i with i ^ s, and the layout turns each stride into one of
+three moves:
 
-VMEM bound: the single block names 4 input lanes of (P,) and 4 output
-lanes of (2P,) u32 in its specs — 48 B per element — so a 16 MB-VMEM
-TPU core admits P up to SINGLE_PASS_MAX_P = 2^18 (the analysis/blocks
-census puts the exact ceiling at ~2^18.4; the next power of two would
-need 24 MB).  Past that bound ``sorted_intersect_tiled`` runs the
-SAME merge network as a multi-pass grid schedule (DESIGN.md §5): the
-bitonic network is oblivious, so its stages split freely across
-dispatches —
+  s < 128          lane partner: ``pltpu.roll`` along lanes by ±s and a
+                   lane-iota mask picks which roll is the partner;
+  128 ≤ s < 128·SB row partner inside a sub-block of SB rows: the same
+                   with sublane rolls by ±s/128 rows;
+  s ≥ 128·SB       row-block partner: two aligned (SB, 128) sub-blocks
+                   at row distance s/128 exchange as a pair.
 
-  cross passes   stride s ≥ chunk/2: one grid kernel per stage; every
-                 grid step loads one (x, y) tile pair at distance s,
-                 compare-exchanges elementwise, writes it back
-                 (input/output aliased, so VMEM holds one tile pair).
-  local pass     strides < chunk/2: one grid kernel over contiguous
-                 chunks; each chunk runs all its remaining stages
-                 VMEM-resident, exactly the single-block kernel at
-                 chunk scale.
+The lower slot keeps the lexicographic min of (kh, kl), the upper the
+max.  The network is a sorting network, so ANY schedule of its stages
+is bitwise equal to the jnp oracle (``ref.merge_network``); selection
+and rank recovery (``ref.select_rank``: predecessor compare + cumsum)
+stream over the merged lanes in XLA outside the kernels.
 
-Stage-for-stage the tiled schedule performs the identical
-compare-exchanges in the identical order, so its outputs are bitwise
-equal to the single-block kernel and the jnp ref.  Selection/rank
-recovery (elementwise predecessor compare + one cumsum) streams over
-the merged lanes outside the kernels — it has no cross-stage VMEM
-residency to exploit.
+Single pass (P ≤ SINGLE_PASS_MAX_P): one grid step holds the whole
+(2P/128, 128) lane pair in VMEM and runs all log2(2P) stages there, in
+fori_loops over sub-blocks so the kernel body stays small.  Past that
+bound ``sorted_intersect_tiled`` splits the same stages across
+dispatches (DESIGN.md §5):
+
+  cross passes   strides whose 2s-blocks exceed a chunk: one grid
+                 kernel per stage; each grid step loads one (x, y)
+                 (tile, 128) pair at row distance s/128, exchanges it,
+                 writes it back (input/output aliased);
+  local pass     the remaining strides: one grid kernel over contiguous
+                 (chunk, 128) blocks, each running the single-pass body
+                 at chunk scale.
+
+VMEM bounds, as the v5e compiler admits them under its default 16 MiB
+scoped VMEM: a gridded pass double-buffers 2 input and 2 output
+(chunk/128, 128) u32 blocks — 64 B per element of the per-side span —
+so a span of 2^18 (16 MiB) compiles and 2^19 is refused ("Scoped
+allocation with size 32.00M and limit 16.00M").  One bound,
+SINGLE_PASS_MAX_P = 2^18, therefore caps both the single pass (the
+engine vmaps it over pairs, which gives it a grid and the same double
+buffers) and the tiled local chunk, which runs the single-pass body;
+``tests/test_tpu_compile.py`` compiles both paths for v5e, and
+``analysis/blocks.py`` counts the same 64 B.
 
 Padding contract (ops.py): P is a power of two; A pads with PAD_A,
 B with PAD_B — distinct sentinels with the top bit set, so pads sort
@@ -44,137 +52,202 @@ last and can never count as matches (real keys are 63-bit).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.sorted_intersect import ref
 
-# Per-side span of one VMEM-resident chunk in the tiled schedule (the
-# local pass holds 2 aliased key lanes of 2·PALLAS_MAX_P elements —
-# half the single-pass kernel's 8-lane footprint, so it reaches 2x
-# further).  The single-pass kernel is admitted only up to
-# SINGLE_PASS_MAX_P: at 48 B/element its 8 named lanes exceed 16 MB
-# beyond P ≈ 2^18.4, so the next power of two is the boundary.
-PALLAS_MAX_P = 1 << 19
-SINGLE_PASS_MAX_P = 1 << 18
+LANES = 128
+MIN_ROWS = 8                  # one (8, 128) u32 tile: the smallest block
+MIN_LENGTH = MIN_ROWS * LANES
+SUB_ROWS = 64                 # rows per in-kernel sub-block (8 vregs/lane)
+
+SINGLE_PASS_MAX_P = 1 << 18   # per-side span of one merge pass in VMEM
+CROSS_TILE_ROWS = 512         # rows per cross-pass tile (256 KiB/lane)
 
 
-def _merge_kernel(a_kh_ref, a_kl_ref, b_kh_ref, b_kl_ref,
-                  sel_ref, rank_ref, mkh_ref, mkl_ref):
-    sel, rank, mkh, mkl = ref.sorted_intersect(
-        a_kh_ref[...], a_kl_ref[...], b_kh_ref[...], b_kl_ref[...])
-    sel_ref[...] = sel
-    rank_ref[...] = rank
-    mkh_ref[...] = mkh
-    mkl_ref[...] = mkl
+def _exchange(xh, xl, yh, yl):
+    """Pairwise (x, y) -> (min, max) on the (kh, kl) lane pair."""
+    swap = ref.lex_gt(xh, xl, yh, yl)
+    return (jnp.where(swap, yh, xh), jnp.where(swap, yl, xl),
+            jnp.where(swap, xh, yh), jnp.where(swap, xl, yl))
 
 
-def sorted_intersect_pallas(a_kh, a_kl, b_kh, b_kl, *,
-                            interpret: bool = True):
-    """All inputs (P,) u32, P a power of two, per-side sorted+padded.
-    Returns (sel (2P,) i32, rank (2P,) i32, merged_kh, merged_kl)."""
-    p = a_kh.shape[0]
-    assert p & (p - 1) == 0, p
-    two_p = 2 * p
+def _in_block_stages(kh, kl, s_top: int):
+    """All strides s_top, s_top/2, ..., 1 on one (rows, 128) value whose
+    2·s_top span fits in it: the partner comes from a roll (lanes for
+    s < 128, sublanes above), the iota mask says which roll."""
+    rows = kh.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, kh.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, kh.shape, 1)
+    s = s_top
+    while s >= 1:
+        if s >= LANES:
+            axis, size, dist, pos = 0, rows, s // LANES, row
+        else:
+            axis, size, dist, pos = 1, LANES, s, lane
+        lower = (pos & dist) == 0
+        up = lambda v: pltpu.roll(v, size - dist, axis)    # v[i + dist]
+        down = lambda v: pltpu.roll(v, dist, axis)         # v[i - dist]
+        ph = jnp.where(lower, up(kh), down(kh))
+        pl_ = jnp.where(lower, up(kl), down(kl))
+        # lower slot takes the partner iff it is smaller, upper iff not
+        take = ref.lex_gt(kh, kl, ph, pl_) == lower
+        kh = jnp.where(take, ph, kh)
+        kl = jnp.where(take, pl_, kl)
+        s //= 2
+    return kh, kl
+
+
+def _merge_block(rows: int, s_top: int, kh_ref, kl_ref, okh_ref, okl_ref):
+    """Run strides s_top..1 over one (rows, 128) VMEM block, in place in
+    the output refs: row-block exchanges for strides ≥ 128·SB, then one
+    sweep of SB-row sub-blocks for everything below."""
+    sb = min(SUB_ROWS, rows)
+    n_sub = rows // sb
+
+    def copy(q, c):
+        r = pl.multiple_of(q * sb, sb)
+        okh_ref[pl.ds(r, sb), :] = kh_ref[pl.ds(r, sb), :]
+        okl_ref[pl.ds(r, sb), :] = kl_ref[pl.ds(r, sb), :]
+        return c
+    jax.lax.fori_loop(0, n_sub, copy, 0)
+
+    s = s_top
+    while s >= sb * LANES:
+        t = s // LANES                       # row distance, multiple of sb
+
+        def pair(q, c, t=t):
+            u = q * sb
+            x = pl.multiple_of(u + (u & ~(t - 1)), sb)   # block·2t + offset
+            y = pl.multiple_of(x + t, sb)
+            xh, xl, yh, yl = _exchange(
+                okh_ref[pl.ds(x, sb), :], okl_ref[pl.ds(x, sb), :],
+                okh_ref[pl.ds(y, sb), :], okl_ref[pl.ds(y, sb), :])
+            okh_ref[pl.ds(x, sb), :] = xh
+            okl_ref[pl.ds(x, sb), :] = xl
+            okh_ref[pl.ds(y, sb), :] = yh
+            okl_ref[pl.ds(y, sb), :] = yl
+            return c
+        jax.lax.fori_loop(0, n_sub // 2, pair, 0)
+        s //= 2
+
+    def local(q, c):
+        r = pl.multiple_of(q * sb, sb)
+        kh, kl = _in_block_stages(okh_ref[pl.ds(r, sb), :],
+                                  okl_ref[pl.ds(r, sb), :], s)
+        okh_ref[pl.ds(r, sb), :] = kh
+        okl_ref[pl.ds(r, sb), :] = kl
+        return c
+    if s >= 1:
+        jax.lax.fori_loop(0, n_sub, local, 0)
+
+
+def _merge_pass(kh, kl, chunk_rows: int, s_top: int, interpret: bool):
+    """(R, 128) lane pair -> the same with strides s_top..1 applied
+    inside every (chunk_rows, 128) block, one block per grid step."""
+    rows = kh.shape[0]
+    spec = pl.BlockSpec((chunk_rows, LANES), lambda i: (i, 0))
     return pl.pallas_call(
-        _merge_kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((p,), lambda i: (0,))] * 4,
-        out_specs=[pl.BlockSpec((two_p,), lambda i: (0,))] * 4,
-        out_shape=[jax.ShapeDtypeStruct((two_p,), jnp.int32)] * 2 +
-                  [jax.ShapeDtypeStruct((two_p,), jnp.uint32)] * 2,
+        functools.partial(_merge_block, chunk_rows, s_top),
+        name="sorted_intersect_merge",
+        grid=(rows // chunk_rows,),
+        in_specs=[spec, spec],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct(kh.shape, jnp.uint32)] * 2,
+        input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(a_kh, a_kl, b_kh, b_kl)
+    )(kh, kl)
 
-
-# --------------------------------------------------- tiled multi-pass merge
 
 def _cross_stage_kernel(kh_ref, kl_ref, okh_ref, okl_ref):
-    """One compare-exchange stage tile: block (1, 2, T) holds the x tile
-    (dim-1 index 0) and its partner y tile at distance s (index 1)."""
-    xh, yh = kh_ref[0, 0, :], kh_ref[0, 1, :]
-    xl, yl = kl_ref[0, 0, :], kl_ref[0, 1, :]
-    swap = (xh > yh) | ((xh == yh) & (xl > yl))
-    okh_ref[0, 0, :] = jnp.where(swap, yh, xh)
-    okh_ref[0, 1, :] = jnp.where(swap, xh, yh)
-    okl_ref[0, 0, :] = jnp.where(swap, yl, xl)
-    okl_ref[0, 1, :] = jnp.where(swap, xl, yl)
+    """One compare-exchange stage tile: block (1, 2, T, 128) holds the x
+    tile (dim-1 index 0) and its partner y tile at row distance t."""
+    out = _exchange(kh_ref[0, 0], kl_ref[0, 0], kh_ref[0, 1], kl_ref[0, 1])
+    okh_ref[0, 0], okl_ref[0, 0], okh_ref[0, 1], okl_ref[0, 1] = out
 
 
-def _cross_stage(kh, kl, s: int, tile: int, interpret: bool):
-    """Stride-s compare-exchange over length-L lanes as a grid pass.
-
-    Reshaping to (L/2s, 2, s) puts every (c[i], c[i+s]) pair at dim-1
-    indices (0, 1) of one row, so a (1, 2, T) block is a self-contained
-    tile pair and the grid streams s/T tiles per 2s-block through VMEM.
-    """
-    length = kh.shape[0]
-    r = length // (2 * s)
-    t = min(s, tile)
-    spec = pl.BlockSpec((1, 2, t), lambda i, j: (i, 0, j))
+def _cross_stage(kh, kl, s: int, tile_rows: int, interpret: bool):
+    """Stride-s stage over (R, 128) lanes as a grid pass: reshaping to
+    (R/2t, 2, t, 128), t = s/128 rows, puts every (c[i], c[i+s]) pair at
+    dim-1 indices (0, 1), so a (1, 2, T, 128) block is a self-contained
+    tile pair and the grid streams t/T tiles per 2t-row block."""
+    rows = kh.shape[0]
+    t = s // LANES
+    r = rows // (2 * t)
+    tt = min(t, tile_rows)
+    spec = pl.BlockSpec((1, 2, tt, LANES), lambda i, j: (i, 0, j, 0))
     okh, okl = pl.pallas_call(
         _cross_stage_kernel,
-        grid=(r, s // t),
+        name="sorted_intersect_cross",
+        grid=(r, t // tt),
         in_specs=[spec, spec],
         out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((r, 2, s), jnp.uint32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((r, 2, t, LANES), jnp.uint32)] * 2,
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(kh.reshape(r, 2, s), kl.reshape(r, 2, s))
-    return okh.reshape(length), okl.reshape(length)
+    )(kh.reshape(r, 2, t, LANES), kl.reshape(r, 2, t, LANES))
+    return okh.reshape(rows, LANES), okl.reshape(rows, LANES)
 
 
-def _local_stages_kernel(kh_ref, kl_ref, okh_ref, okl_ref):
-    """Finish all strides < chunk/2 with the chunk VMEM-resident."""
-    lanes = [kh_ref[0, :], kl_ref[0, :]]
-    s = lanes[0].shape[0] // 2
-    while s >= 1:
-        lanes = ref._compare_exchange(lanes, s)
-        s //= 2
-    okh_ref[0, :] = lanes[0]
-    okl_ref[0, :] = lanes[1]
-
-
-def _local_stages(kh, kl, chunk: int, interpret: bool):
-    length = kh.shape[0]
-    g = length // chunk
-    spec = pl.BlockSpec((1, chunk), lambda i: (i, 0))
-    okh, okl = pl.pallas_call(
-        _local_stages_kernel,
-        grid=(g,),
-        in_specs=[spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((g, chunk), jnp.uint32)] * 2,
-        input_output_aliases={0: 0, 1: 1},
-        interpret=interpret,
-    )(kh.reshape(g, chunk), kl.reshape(g, chunk))
-    return okh.reshape(length), okl.reshape(length)
-
-
-def sorted_intersect_tiled(a_kh, a_kl, b_kh, b_kl, *,
-                           interpret: bool = True,
-                           chunk_p: int = PALLAS_MAX_P,
-                           tile: int = PALLAS_MAX_P):
-    """Multi-pass merge for P past the single-block bound.  Same
-    signature/outputs as ``sorted_intersect_pallas``; ``chunk_p`` caps
-    the per-chunk VMEM residency at 2·chunk_p elements per lane and
-    ``tile`` the per-step footprint of the cross passes (defaults keep
-    both at the single-block bound; tests shrink them to exercise the
-    multi-pass structure at small P)."""
+def _layout(a_kh, a_kl, b_kh, b_kl):
+    """Padded (P,) sides -> the (R, 128) bitonic lane pair (at least one
+    (8, 128) tile) and the merged length 2P to keep."""
     p = a_kh.shape[0]
     assert p & (p - 1) == 0, p
-    chunk = min(2 * chunk_p, 2 * p)
-    kh = jnp.concatenate([a_kh, jnp.flip(b_kh)])
-    kl = jnp.concatenate([a_kl, jnp.flip(b_kl)])
-    s = p
+    length = max(2 * p, MIN_LENGTH)
+    kh, kl = ref.bitonic_lanes(a_kh, a_kl, b_kh, b_kl, length)
+    return kh.reshape(-1, LANES), kl.reshape(-1, LANES), 2 * p
+
+
+def _finish(kh, kl, two_p: int):
+    return kh.reshape(-1)[:two_p], kl.reshape(-1)[:two_p]
+
+
+def merge_pallas(a_kh, a_kl, b_kh, b_kl, *, interpret: bool):
+    """Single-pass merge.  Inputs (P,) u32, P a power of two, per-side
+    sorted+padded -> merged (kh, kl) lanes, each (2P,) u32."""
+    kh, kl, two_p = _layout(a_kh, a_kl, b_kh, b_kl)
+    kh, kl = _merge_pass(kh, kl, kh.shape[0], kh.size // 2, interpret)
+    return _finish(kh, kl, two_p)
+
+
+def merge_tiled(a_kh, a_kl, b_kh, b_kl, *, interpret: bool,
+                chunk_p: int = SINGLE_PASS_MAX_P,
+                tile: int = CROSS_TILE_ROWS * LANES):
+    """Multi-pass merge for P past the single-block bound, same
+    signature/outputs as ``merge_pallas``; ``chunk_p`` caps the local
+    pass's VMEM residency at 2·chunk_p elements per lane and ``tile``
+    the per-step footprint (elements) of the cross passes — both
+    multiples of one (8, 128) tile (tests shrink them to exercise the
+    multi-pass structure at small P)."""
+    kh, kl, two_p = _layout(a_kh, a_kl, b_kh, b_kl)
+    length = kh.size
+    chunk = min(2 * chunk_p, length)
+    assert chunk % MIN_LENGTH == 0 and tile % MIN_LENGTH == 0, (chunk, tile)
+    s = length // 2
     while 2 * s > chunk:          # stages whose 2s-blocks exceed a chunk
-        kh, kl = _cross_stage(kh, kl, s, tile, interpret)
+        kh, kl = _cross_stage(kh, kl, s, tile // LANES, interpret)
         s //= 2
-    kh, kl = _local_stages(kh, kl, chunk, interpret)
-    origin = (kl & jnp.uint32(1)).astype(jnp.int32)
-    rank = jnp.cumsum(origin)
-    prev_match = (kh[1:] == kh[:-1]) & (kl[1:] == kl[:-1] + jnp.uint32(1))
-    sel = (jnp.concatenate([jnp.zeros((1,), bool), prev_match])
-           & (origin == 1) & (kh < jnp.uint32(ref.VALID_LIMIT)))
-    return sel.astype(jnp.int32), rank, kh, kl
+    kh, kl = _merge_pass(kh, kl, chunk // LANES, s, interpret)
+    return _finish(kh, kl, two_p)
+
+
+def sorted_intersect_pallas(a_kh, a_kl, b_kh, b_kl, *, interpret: bool):
+    """(P,) sides -> (sel (2P,) i32, rank (2P,) i32, merged_kh,
+    merged_kl) through the single-pass merge."""
+    kh, kl = merge_pallas(a_kh, a_kl, b_kh, b_kl, interpret=interpret)
+    return (*ref.select_rank(kh, kl), kh, kl)
+
+
+def sorted_intersect_tiled(a_kh, a_kl, b_kh, b_kl, *, interpret: bool,
+                           **split):
+    """``sorted_intersect_pallas`` through the multi-pass merge
+    (``split``: ``chunk_p``/``tile`` of ``merge_tiled``)."""
+    kh, kl = merge_tiled(a_kh, a_kl, b_kh, b_kl, interpret=interpret,
+                         **split)
+    return (*ref.select_rank(kh, kl), kh, kl)

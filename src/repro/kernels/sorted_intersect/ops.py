@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.padding import INTERPRET
+from repro.kernels.padding import interpret, record_path
 from repro.kernels.sorted_intersect import ref
 from repro.kernels.sorted_intersect.kernel import (SINGLE_PASS_MAX_P,
                                                    sorted_intersect_pallas,
@@ -38,9 +38,13 @@ def pack_keys(tag_hi: jnp.ndarray, tag_lo: jnp.ndarray, origin: int
 
 
 def _pad_side(kh, kl, pad, p):
-    n = kh.shape[0]
-    return (jnp.full((p,), pad[0], jnp.uint32).at[:n].set(kh),
-            jnp.full((p,), pad[1], jnp.uint32).at[:n].set(kl))
+    # appended constant tail, not a scatter into a filled array: XLA 0.9's
+    # algebraic simplifier aborts on reverse(scatter) of the B side
+    fill = p - kh.shape[0]
+    if not fill:
+        return kh, kl
+    return (jnp.concatenate([kh, jnp.full((fill,), pad[0], jnp.uint32)]),
+            jnp.concatenate([kl, jnp.full((fill,), pad[1], jnp.uint32)]))
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
@@ -55,12 +59,13 @@ def sorted_intersect(a_kh: jnp.ndarray, a_kl: jnp.ndarray,
     b_kh, b_kl = _pad_side(b_kh, b_kl, PAD_B, p)
     if impl == "ref":
         return ref.sorted_intersect(a_kh, a_kl, b_kh, b_kl)
-    # past the single-block VMEM bound (48 B/element: P > 2^18 blows
-    # the 16 MB budget) the same merge network runs as a multi-pass
-    # grid schedule (cross-stage passes + VMEM-resident chunk finish) —
-    # bitwise-identical outputs, no jnp fallback
+    # past the single-block VMEM bound the same merge network runs as a
+    # multi-pass grid schedule (cross-stage passes + VMEM-resident chunk
+    # finish) — bitwise-identical outputs, no jnp fallback
     if p > SINGLE_PASS_MAX_P:
+        record_path("sorted_intersect", "tiled")
         return sorted_intersect_tiled(a_kh, a_kl, b_kh, b_kl,
-                                      interpret=INTERPRET)
+                                      interpret=interpret())
+    record_path("sorted_intersect", "single_pass")
     return sorted_intersect_pallas(a_kh, a_kl, b_kh, b_kl,
-                                   interpret=INTERPRET)
+                                   interpret=interpret())

@@ -39,7 +39,7 @@ def _bottom_kernel(relu: bool, x_ref, w_ref, b_ref, out_ref):
 
 def splitnn_bottom_pallas(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
                           relu: bool, block_b: int = 512,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool) -> jnp.ndarray:
     """x (M, Bp, dp) f32, w (M, dp, op) f32, b (M, 1, op) f32 (padded).
 
     Bp % block_b == 0; dp % 128 == 0; op % 128 == 0.  Returns
@@ -53,6 +53,7 @@ def splitnn_bottom_pallas(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
     kernel = functools.partial(_bottom_kernel, relu)
     return pl.pallas_call(
         kernel,
+        name="splitnn_bottom",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_b, dp), lambda m, i: (m, i, 0)),
@@ -84,7 +85,7 @@ def splitnn_bottom_int8_pallas(xq: jnp.ndarray, sx: jnp.ndarray,
                                wq: jnp.ndarray, sw: jnp.ndarray,
                                b: jnp.ndarray, *, relu: bool,
                                block_b: int = 512,
-                               interpret: bool = True) -> jnp.ndarray:
+                               interpret: bool) -> jnp.ndarray:
     """int8 twin of :func:`splitnn_bottom_pallas` (DESIGN.md §12).
 
     xq (M, Bp, dp) i8, sx (M, 1, Bp) f32 per-row dequant scales (lane
@@ -102,6 +103,7 @@ def splitnn_bottom_int8_pallas(xq: jnp.ndarray, sx: jnp.ndarray,
     kernel = functools.partial(_bottom_int8_kernel, relu)
     return pl.pallas_call(
         kernel,
+        name="splitnn_bottom_int8",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_b, dp), lambda m, i: (m, i, 0)),
@@ -119,18 +121,61 @@ def splitnn_bottom_int8_pallas(xq: jnp.ndarray, sx: jnp.ndarray,
 # ------------------------------------------------- scalar-prefetch gather
 
 
-def _bottom_gather_kernel(relu: bool, block_b: int, idx_ref,
-                          x_ref, w_ref, b_ref, out_ref):
+def _slab_spec(np_: int, dp: int):
+    """Client m's full slab as one block.  Its index changes only with
+    the outer client axis, so one buffer suffices: double-buffering it
+    would halve the slab the scoped VMEM admits (``GATHER_VMEM_BUDGET``)
+    to hide one DMA per client."""
+    return pl.BlockSpec((1, np_, dp), lambda m, i, idx_ref: (m, 0, 0),
+                        pipeline_mode=pl.Buffered(1))
+
+
+def _gather_rows(idx_ref, x_ref, buf_ref, block_b: int):
+    """Copy the batch tile's scheduled slab rows into the VMEM scratch
+    tile, one (1, dp) row store each (prefetched indices, grid axis 1 =
+    batch tile)."""
     i = pl.program_id(1)
-    dp = x_ref.shape[2]
 
-    def gather_row(r, acc):
+    def gather_row(r, c):
         j = idx_ref[i * block_b + r]              # prefetched schedule slot
-        row = x_ref[0, pl.ds(j, 1), :]            # (1, dp) dynamic slice
-        return jax.lax.dynamic_update_slice(acc, row, (r, 0))
+        buf_ref[pl.ds(r, 1), :] = x_ref[0, pl.ds(j, 1), :]
+        return c
 
-    x = jax.lax.fori_loop(0, block_b, gather_row,
-                          jnp.zeros((block_b, dp), jnp.float32))
+    jax.lax.fori_loop(0, block_b, gather_row, 0)
+
+
+def pack_int8_rows(xq: jnp.ndarray) -> jnp.ndarray:
+    """(M, N, dp) i8 -> (M, ceil(N/4), dp) i32, rows 4q..4q+3 as bytes
+    0..3 of word row q.  Mosaic tiles i8 as (32, 128), so a single i8
+    row at a dynamic offset cannot be loaded; a 32-bit word row can, at
+    the same 1 byte per element of VMEM."""
+    m, n, dp = xq.shape
+    n4 = -(-n // 4) * 4
+    u = jax.lax.bitcast_convert_type(xq, jnp.uint8).astype(jnp.uint32)
+    u = jnp.pad(u, ((0, 0), (0, n4 - n), (0, 0))).reshape(m, n4 // 4, 4, dp)
+    shifts = (8 * jnp.arange(4, dtype=jnp.uint32))[None, None, :, None]
+    word = jnp.bitwise_or.reduce(u << shifts, axis=2)
+    return jax.lax.bitcast_convert_type(word, jnp.int32)
+
+
+def _gather_packed_rows(idx_ref, x_ref, buf_ref, block_b: int):
+    """``_gather_rows`` over a ``pack_int8_rows`` slab: load word row
+    j // 4, sign-extend byte j % 4 into the i32 scratch tile."""
+    i = pl.program_id(1)
+
+    def gather_row(r, c):
+        j = idx_ref[i * block_b + r]
+        word = x_ref[0, pl.ds(j >> 2, 1), :]      # (1, dp) i32, 4 rows
+        buf_ref[pl.ds(r, 1), :] = (word << (24 - 8 * (j & 3))) >> 24
+        return c
+
+    jax.lax.fori_loop(0, block_b, gather_row, 0)
+
+
+def _bottom_gather_kernel(relu: bool, block_b: int, idx_ref,
+                          x_ref, w_ref, b_ref, out_ref, buf_ref):
+    _gather_rows(idx_ref, x_ref, buf_ref, block_b)
+    x = buf_ref[...]                              # (bb, dp) gathered tile
     w = w_ref[0]                                  # (dp, op) resident weights
     a = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -141,7 +186,7 @@ def _bottom_gather_kernel(relu: bool, block_b: int, idx_ref,
 def splitnn_bottom_gather_pallas(idx: jnp.ndarray, x: jnp.ndarray,
                                  w: jnp.ndarray, b: jnp.ndarray, *,
                                  relu: bool, block_b: int = 512,
-                                 interpret: bool = True) -> jnp.ndarray:
+                                 interpret: bool) -> jnp.ndarray:
     """Gather-fused forward: the per-step ``slab[:, idx, :]`` minibatch
     gather moves INTO the kernel via scalar prefetch
     (``pltpu.PrefetchScalarGridSpec``), so the gathered batch never
@@ -169,16 +214,18 @@ def splitnn_bottom_gather_pallas(idx: jnp.ndarray, x: jnp.ndarray,
         num_scalar_prefetch=1,
         grid=(m, bp // block_b),
         in_specs=[
-            pl.BlockSpec((1, np_, dp), lambda m, i, idx_ref: (m, 0, 0)),
+            _slab_spec(np_, dp),
             pl.BlockSpec((1, dp, op), lambda m, i, idx_ref: (m, 0, 0)),
             pl.BlockSpec((1, 1, op), lambda m, i, idx_ref: (m, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_b, op),
                                lambda m, i, idx_ref: (m, i, 0)),
+        scratch_shapes=[pltpu.VMEM((block_b, dp), jnp.float32)],
     )
     kernel = functools.partial(_bottom_gather_kernel, relu, block_b)
     return pl.pallas_call(
         kernel,
+        name="splitnn_bottom_gather",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, bp, op), jnp.float32),
         interpret=interpret,
@@ -187,17 +234,9 @@ def splitnn_bottom_gather_pallas(idx: jnp.ndarray, x: jnp.ndarray,
 
 def _bottom_int8_gather_kernel(relu: bool, block_b: int, idx_ref,
                                xq_ref, sx_ref, wq_ref, sw_ref, b_ref,
-                               out_ref):
-    i = pl.program_id(1)
-    dp = xq_ref.shape[2]
-
-    def gather_row(r, acc):
-        j = idx_ref[i * block_b + r]              # prefetched schedule slot
-        row = xq_ref[0, pl.ds(j, 1), :]           # (1, dp) int8 dynamic slice
-        return jax.lax.dynamic_update_slice(acc, row, (r, 0))
-
-    xq = jax.lax.fori_loop(0, block_b, gather_row,
-                           jnp.zeros((block_b, dp), jnp.int8))
+                               out_ref, buf_ref):
+    _gather_packed_rows(idx_ref, xq_ref, buf_ref, block_b)
+    xq = buf_ref[...].astype(jnp.int8)            # (bb, dp) int8 tile
     acc = jax.lax.dot_general(xq, wq_ref[0], (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.int32)
     scale = sx_ref[0].reshape(-1, 1) * sw_ref[0]
@@ -205,16 +244,19 @@ def _bottom_int8_gather_kernel(relu: bool, block_b: int, idx_ref,
     out_ref[0] = jnp.maximum(a, 0.0) if relu else a
 
 
-def splitnn_bottom_int8_gather_pallas(idx: jnp.ndarray, xq: jnp.ndarray,
+def splitnn_bottom_int8_gather_pallas(idx: jnp.ndarray, xw: jnp.ndarray,
                                       sx: jnp.ndarray, wq: jnp.ndarray,
                                       sw: jnp.ndarray, b: jnp.ndarray, *,
                                       relu: bool, block_b: int = 512,
-                                      interpret: bool = True) -> jnp.ndarray:
+                                      interpret: bool) -> jnp.ndarray:
     """int8 twin of :func:`splitnn_bottom_gather_pallas`.
 
     The resident slab is int8 — 1 byte/element instead of 4 — so the
     gather fusion stays within ``GATHER_VMEM_BUDGET`` at 4x the slab
     rows of the f32 variant (ops.py admits with a 1-byte element size).
+    It arrives packed four rows to an i32 word row (``xw`` =
+    ``pack_int8_rows(xq)``, (M, ceil(N/4), dp) i32); rows are unpacked
+    into an i32 scratch tile and narrowed to i8 for the MXU matmul.
     Per-row scales commute with the row gather, so ``sx`` here is the
     ALREADY-GATHERED (M, 1, Bp) f32 scale vector for the scheduled rows
     (the (B,)-long ``jnp.take`` on the tiny exponent vector happens
@@ -223,7 +265,7 @@ def splitnn_bottom_int8_gather_pallas(idx: jnp.ndarray, xq: jnp.ndarray,
     scan, so XLA hoists it out of the step loop — the slab is quantized
     once per epoch, not once per step.
     """
-    m, np_, dp = xq.shape
+    m, nw, dp = xw.shape
     op = wq.shape[2]
     bp = idx.shape[0]
     assert bp % block_b == 0 and dp % 128 == 0 and op % 128 == 0, \
@@ -232,7 +274,7 @@ def splitnn_bottom_int8_gather_pallas(idx: jnp.ndarray, xq: jnp.ndarray,
         num_scalar_prefetch=1,
         grid=(m, bp // block_b),
         in_specs=[
-            pl.BlockSpec((1, np_, dp), lambda m, i, idx_ref: (m, 0, 0)),
+            _slab_spec(nw, dp),
             pl.BlockSpec((1, 1, block_b), lambda m, i, idx_ref: (m, 0, i)),
             pl.BlockSpec((1, dp, op), lambda m, i, idx_ref: (m, 0, 0)),
             pl.BlockSpec((1, 1, op), lambda m, i, idx_ref: (m, 0, 0)),
@@ -240,11 +282,13 @@ def splitnn_bottom_int8_gather_pallas(idx: jnp.ndarray, xq: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, block_b, op),
                                lambda m, i, idx_ref: (m, i, 0)),
+        scratch_shapes=[pltpu.VMEM((block_b, dp), jnp.int32)],
     )
     kernel = functools.partial(_bottom_int8_gather_kernel, relu, block_b)
     return pl.pallas_call(
         kernel,
+        name="splitnn_bottom_int8_gather",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, bp, op), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(idx, jnp.int32), xq, sx, wq, sw, b)
+    )(jnp.asarray(idx, jnp.int32), xw, sx, wq, sw, b)

@@ -38,13 +38,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.padding import (GATHER_VMEM_BUDGET, INTERPRET,
+from repro.kernels.padding import (GATHER_VMEM_BUDGET, interpret,
                                    pad_bottom_blocks,
                                    pad_bottom_blocks_gather, pad_gather_idx,
-                                   round_up)
+                                   record_path, round_up)
 from repro.kernels.splitnn_bottom.kernel import (
-    splitnn_bottom_gather_pallas, splitnn_bottom_int8_gather_pallas,
-    splitnn_bottom_int8_pallas, splitnn_bottom_pallas)
+    pack_int8_rows, splitnn_bottom_gather_pallas,
+    splitnn_bottom_int8_gather_pallas, splitnn_bottom_int8_pallas,
+    splitnn_bottom_pallas)
 from repro.kernels.splitnn_bottom.ref import (splitnn_bottom_int8_ref,
                                               splitnn_bottom_ref)
 from repro.quant import quantize_columns, quantize_rows
@@ -74,14 +75,17 @@ def _dense_forward(x, w, b, relu, impl, block_b, quant=None):
     if quant == "int8":
         xq, sx, wq, sw = _int8_operands(xp, wp)
         if impl == "pallas":
+            record_path("splitnn_bottom", "int8_dense")
             out = splitnn_bottom_int8_pallas(xq, sx, wq, sw, bp, relu=relu,
-                                             block_b=bb, interpret=INTERPRET)
+                                             block_b=bb,
+                                             interpret=interpret())
         else:
             out = splitnn_bottom_int8_ref(xq, sx, wq, sw, bp, relu=relu)
         return out[:, :n, :o]
     if impl == "pallas":
+        record_path("splitnn_bottom", "dense")
         out = splitnn_bottom_pallas(xp, wp, bp, relu=relu, block_b=bb,
-                                    interpret=INTERPRET)
+                                    interpret=interpret())
     else:
         out = splitnn_bottom_ref(xp, wp, bp, relu=relu)
     return out[:, :n, :o]
@@ -100,7 +104,9 @@ def _forward(x, w, b, relu, impl, block_b, idx=None, quant=None):
     if impl == "pallas":
         dp = round_up(x.shape[2], 128)
         elem = 1 if kq else 4     # int8 slab: 4x the VMEM reach
-        if INTERPRET or x.shape[1] * dp * elem <= GATHER_VMEM_BUDGET:
+        tag = "int8_gather" if kq else "gather"
+        if interpret() or x.shape[1] * dp * elem <= GATHER_VMEM_BUDGET:
+            record_path("splitnn_bottom", tag + "_fused")
             idx_p, bb, bsz = pad_gather_idx(idx, block_b)
             xp, wp, bp = pad_bottom_blocks_gather(x, w, b)
             if kq:
@@ -110,13 +116,14 @@ def _forward(x, w, b, relu, impl, block_b, idx=None, quant=None):
                 # slab gather into the kernel
                 sxg = jnp.take(sx, idx_p, axis=2)
                 out = splitnn_bottom_int8_gather_pallas(
-                    idx_p, xq, sxg, wq, sw, bp, relu=relu, block_b=bb,
-                    interpret=INTERPRET)
+                    idx_p, pack_int8_rows(xq), sxg, wq, sw, bp, relu=relu,
+                    block_b=bb, interpret=interpret())
             else:
                 out = splitnn_bottom_gather_pallas(idx_p, xp, wp, bp,
                                                    relu=relu, block_b=bb,
-                                                   interpret=INTERPRET)
+                                                   interpret=interpret())
             return out[:, :bsz, :o]
+        record_path("splitnn_bottom", tag + "_fallback")
     # ref oracle (and the past-VMEM-budget fallback): gather, then the
     # dense pass — the bitwise contract the fused kernel must match
     # (per-row int8 scales make quantize-then-gather == gather-then-

@@ -76,7 +76,7 @@ def _ssd_kernel(nc: int, x_ref, dt_ref, a_ref, b_ref, c_ref,
         fs_ref[0, 0] = new_state
 
 
-def ssd_scan_pallas(x, dt, A, B, C, *, chunk: int, interpret: bool = True):
+def ssd_scan_pallas(x, dt, A, B, C, *, chunk: int, interpret: bool):
     """x (B,H,nc,L,P), dt (B,H,nc,L), A (H,), B/C (B,nc,L,N) — all f32,
     L == chunk. Returns (y (B,H,nc,L,P), final_state (B,H,P,N))."""
     bsz, h, nc, l, p = x.shape

@@ -7,14 +7,12 @@ the kernel's (B,H,nc,L,·) blocked layout, restores after.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.padding import interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -39,6 +37,6 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128):
     bk = B.astype(jnp.float32).reshape(bsz, nc, chunk, n)
     ck = C.astype(jnp.float32).reshape(bsz, nc, chunk, n)
     y, fs = ssd_scan_pallas(xk, dtk, A.astype(jnp.float32), bk, ck,
-                            chunk=chunk, interpret=INTERPRET)
+                            chunk=chunk, interpret=interpret())
     y = y.transpose(0, 2, 3, 1, 4).reshape(bsz, s, h, p)[:, :s_orig]
     return y, fs

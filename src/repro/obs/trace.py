@@ -37,7 +37,7 @@ Finished spans append under a lock.  Chrome-trace export keys lanes by
 
 ``Tracer(jax_profiler=True)`` additionally brackets every span with
 ``jax.profiler.TraceAnnotation`` — opt-in, imported lazily — so a
-real-TPU run (REPRO_PALLAS_INTERPRET=0) gets the same span taxonomy
+TPU run gets the same span taxonomy
 inside the device profiler's timeline for free.
 """
 from __future__ import annotations

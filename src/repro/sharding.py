@@ -208,11 +208,16 @@ def resolve_train_mesh(mesh, shard_axis: Optional[str] = None):
 
 
 def mesh_axis_size(mesh, name: str) -> int:
+    """Size of mesh axis ``name``.  Raises on a mesh whose axis sizes
+    cannot be read, or that lacks the axis: answering 1 would quietly
+    run a sharded path unsharded."""
     try:
-        return dict(zip(mesh.axis_names, mesh.axis_sizes
-                        if hasattr(mesh, "axis_sizes") else mesh.devices.shape))[name]
-    except Exception:
-        return 1
+        sizes = dict(mesh.shape)
+    except (AttributeError, TypeError, ValueError) as e:
+        raise TypeError(f"cannot read the axis sizes of mesh {mesh!r}") from e
+    if name not in sizes:
+        raise ValueError(f"mesh axis {name!r} not in {tuple(sizes)}")
+    return int(sizes[name])
 
 
 def _filter_entry(entry, axes):

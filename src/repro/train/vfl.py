@@ -304,9 +304,15 @@ def make_score_step(params, cfg, feature_dims: Sequence[int], *,
     is jitted: ``x_slab`` is an (M, B, d_max) feature slab and the
     result is (B, o) outputs, bitwise-equal to ``splitnn_forward`` on
     the same rows (any B; one compile per distinct B).
+
+    Scoring runs on one device: parameters trained on a mesh arrive
+    sharded over it, and a jit over several devices would have to
+    partition the Pallas bottom kernel, which Mosaic cannot do outside
+    a ``shard_map``.  So ``packed`` is gathered onto the default device.
     """
     fd = tuple(int(d) for d in feature_dims)
-    packed = pack_slab_params(params, max(fd))
+    packed = jax.device_put(pack_slab_params(params, max(fd)),
+                            jax.devices()[0])
     return packed, _score_step_fn(cfg, len(fd), bottom_impl, int(block_b),
                                   resolve_quant(quant))
 

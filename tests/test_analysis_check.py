@@ -98,14 +98,12 @@ def test_write_refuses_while_lint_fails(tmp_path):
 
 
 def test_census_counts_callbacks_and_f64():
-    from jax.experimental import enable_x64
-
     def fn(x):
         y = jax.pure_callback(
             lambda a: a, jax.ShapeDtypeStruct((), jnp.float32), x)
         return y.astype(jnp.float64) + 1.0
 
-    with enable_x64():
+    with jax.enable_x64(True):
         c = census_program(
             fn, (jax.ShapeDtypeStruct((), jnp.float32),),
             count_donation=False)

@@ -322,25 +322,23 @@ def test_blocks_gather_fallback_boundary():
 
 
 def test_blocks_sorted_intersect_regimes():
-    from repro.analysis.blocks import (SINGLE_PASS_CEILING,
-                                       sorted_intersect_blocks)
-    from repro.kernels.sorted_intersect.kernel import (PALLAS_MAX_P,
-                                                       SINGLE_PASS_MAX_P)
-    # admission boundary: the largest admitted single-pass P fits the
-    # 48 B/element block under 16 MB; one element more routes tiled
-    assert SINGLE_PASS_MAX_P <= SINGLE_PASS_CEILING < PALLAS_MAX_P
+    from repro.analysis.blocks import VMEM_BUDGET, sorted_intersect_blocks
+    from repro.kernels.sorted_intersect.kernel import SINGLE_PASS_MAX_P
+    # admission boundary: the largest admitted single-pass P fills the
+    # double-buffered 64 B/element blocks exactly to 16 MB; the tiled
+    # local chunk spans the same P, one element more routes tiled
+    assert 64 * SINGLE_PASS_MAX_P == VMEM_BUDGET
     at = sorted_intersect_blocks(SINGLE_PASS_MAX_P)
     assert at.ok and not at.note
-    assert at.resident_bytes == 48 * SINGLE_PASS_MAX_P
+    assert at.resident_bytes == 64 * SINGLE_PASS_MAX_P == at.budget
     over = sorted_intersect_blocks(SINGLE_PASS_MAX_P + 1)
     assert over.ok and "tiled" in over.note
-    # the old over-admission band (2^18.4 < P ≤ 2^19 launched single-
-    # pass past 16 MB) is retired: its powers of two now route tiled
+    # 2^19, which the v5e compiler refuses in one pass, routes tiled
     first = sorted_intersect_blocks(1 << 19)
     assert first.ok and "tiled" in first.note
     tiled = sorted_intersect_blocks(1 << 21)
     assert tiled.ok and "tiled" in tiled.note
-    assert tiled.resident_bytes == 4 * 4 * (2 * PALLAS_MAX_P)
+    assert tiled.resident_bytes == 2 * 4 * 4 * (2 * SINGLE_PASS_MAX_P)
 
 
 def test_blocks_default_matrix_all_ok():

@@ -113,16 +113,16 @@ def test_default_sort_keys_off_platform(monkeypatch):
 
 
 def test_default_sort_independent_of_interpret_flag(monkeypatch):
-    """Regression: the default used to key off REPRO_PALLAS_INTERPRET,
-    so a real (non-interpret) CPU run silently got the slow lax.sort
-    path."""
+    """Regression: the default used to key off the kernels' interpret
+    switch, so a real (non-interpret) CPU run silently got the slow
+    lax.sort path.  It keys off the backend alone."""
     import jax
 
     from repro.kernels import padding
 
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     for interpret in (True, False):
-        monkeypatch.setattr(padding, "INTERPRET", interpret)
+        monkeypatch.setattr(padding, "interpret", lambda: interpret)
         assert engine._default_sort(None) == "host"
 
 

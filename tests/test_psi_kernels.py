@@ -1,5 +1,6 @@
 """PSI kernel triplets: psi_prf and sorted_intersect vs their jnp refs
-(bitwise, under REPRO_PALLAS_INTERPRET=1) and vs numpy set semantics."""
+(bitwise, kernels in interpret mode on the CPU backend) and vs numpy set
+semantics."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -113,6 +114,25 @@ def test_intersect_kernel_matches_ref_bitwise():
         assert np.array_equal(np.asarray(k), np.asarray(r))
 
 
+def test_single_pass_row_block_exchanges_match_ref_bitwise():
+    """P = 2^13 puts strides of 64+ rows inside the single-pass block,
+    which the kernel runs as row-block pair exchanges (below that, as
+    sublane/lane rolls): all four outputs stay bitwise equal to the ref."""
+    from repro.kernels.sorted_intersect.kernel import (
+        SUB_ROWS, sorted_intersect_pallas)
+    rng = np.random.default_rng(8)
+    a = np.unique(rng.integers(0, 2**60, 5000, dtype=np.int64))
+    b = np.unique(np.concatenate(
+        [a[:2000], rng.integers(0, 2**60, 4000, dtype=np.int64)]))
+    p = next_pow2(max(len(a), len(b)))
+    assert p >= SUB_ROWS * 128
+    args = _padded_lanes(a, 1, PAD_A, p) + _padded_lanes(b, 0, PAD_B, p)
+    out_k = sorted_intersect_pallas(*args, interpret=True)
+    out_r = si_ref.sorted_intersect(*args)
+    for k, r in zip(out_k, out_r):
+        assert np.array_equal(np.asarray(k), np.asarray(r))
+
+
 def test_intersect_identical_and_disjoint():
     a = np.arange(50, dtype=np.int64) * 3
     for impl in ("ref", "pallas"):
@@ -178,16 +198,16 @@ def _padded_lanes(tags64, origin, pad, p):
 
 
 @pytest.mark.parametrize("na,nb,chunk_p,tile", [
-    (100, 80, 16, 8),      # several cross passes + tiny chunks
-    (1000, 900, 64, 16),   # deeper cross/tile split
-    (5, 3, 8, 8),          # chunk covers everything: zero cross passes
-    (300, 300, 256, 64),   # one cross pass, tile < chunk
+    (3000, 2500, 512, 1024),    # several cross passes + one-tile chunks
+    (9000, 7000, 1024, 1024),   # deeper cross/tile split
+    (5, 3, 512, 1024),          # chunk covers everything: zero cross passes
+    (1500, 1500, 1024, 1024),   # one cross pass, tile < chunk
 ])
 def test_tiled_merge_bitwise_matches_ref(na, nb, chunk_p, tile):
     """The multi-pass grid schedule runs the identical compare-exchange
     network, so its four outputs are bitwise equal to the jnp ref at ANY
-    chunk/tile split (shrunk here so small inputs exercise several cross
-    passes)."""
+    chunk/tile split (shrunk here, down to one (8, 128) tile, so small
+    inputs exercise several cross passes)."""
     from repro.kernels.sorted_intersect.kernel import sorted_intersect_tiled
     rng = np.random.default_rng(na + nb)
     a = np.unique(rng.integers(0, 2**60, na, dtype=np.int64))
@@ -216,13 +236,13 @@ def test_ops_dispatches_tiled_past_vmem_bound():
     from repro.kernels.sorted_intersect import kernel as si_kernel
     from repro.kernels.sorted_intersect import ops as si_ops
 
-    n = 600_000                      # next_pow2 -> 2^20 > PALLAS_MAX_P
+    n = 600_000                  # next_pow2 -> 2^20 > SINGLE_PASS_MAX_P
     rng = np.random.default_rng(0)
     universe = rng.choice(4 * n, size=2 * n, replace=False).astype(np.int64)
     a = np.sort(universe[:n])
     b = np.sort(universe[n // 2: n // 2 + n])
     p = next_pow2(n)
-    assert p > si_kernel.PALLAS_MAX_P
+    assert p > si_kernel.SINGLE_PASS_MAX_P
     a_kh, a_kl = _padded_lanes(a, 1, PAD_A, p)
     b_kh, b_kl = _padded_lanes(b, 0, PAD_B, p)
     with mock.patch.object(si_kernel, "sorted_intersect_pallas",

@@ -312,6 +312,19 @@ def test_resolve_train_mesh_shapes():
         assert (da, nd, ma, nm) == ("data", len(jax.devices()), None, 1)
 
 
+def test_mesh_axis_size_raises_on_unreadable_mesh():
+    """An axis size it cannot read is an error, never a silent 1 that
+    would run a sharded phase unsharded."""
+    from repro.sharding import mesh_axis_size
+
+    m1 = make_data_mesh(1)
+    assert mesh_axis_size(m1, "data") == 1
+    with pytest.raises(ValueError, match="model"):
+        mesh_axis_size(m1, "model")
+    with pytest.raises(TypeError, match="axis sizes"):
+        mesh_axis_size(object(), "data")
+
+
 # ------------------------------------------------------------- end to end
 
 @needs_devices
