@@ -1,0 +1,9 @@
+"""Share (%) of the traced window of alignments in which no operation
+ran on the device."""
+
+
+def read(ctx):
+    if (ctx.span_count("pipeline.run") or not ctx.span_count("align.round")
+            or ctx.trace.window_s <= 0):
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
