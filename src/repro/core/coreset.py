@@ -222,49 +222,53 @@ def _batched_local_clusterings(features: Sequence[np.ndarray], k: int, *,
     n_max, d_max = max(ns), max(ds)
     ragged = len({f.shape for f in features}) > 1
     k_eff = int(min(k, min(ns)))
-    keys = np.stack([np.asarray(jax.random.PRNGKey(seed + 17 * i))
-                     for i in range(m)])
-    if ragged:
-        stacked = np.zeros((m, n_max, d_max), np.float32)
-        for i, f in enumerate(features):
-            stacked[i, :ns[i], :ds[i]] = f
-        n_valid = np.asarray(ns, np.int32)
+    with span("coreset.pack", clients=m, rows=n_max, features=d_max,
+              ragged=ragged):
+        keys = np.stack([np.asarray(jax.random.PRNGKey(seed + 17 * i))
+                         for i in range(m)])
+        if ragged:
+            stacked = np.zeros((m, n_max, d_max), np.float32)
+            for i, f in enumerate(features):
+                stacked[i, :ns[i], :ds[i]] = f
+            n_valid = np.asarray(ns, np.int32)
 
-        def fit_batch(kk, pts, nv):
-            one = lambda kk1, p1, nv1: kmeans_fit(
-                kk1, p1, k_eff, iters=iters, impl=impl, n_valid=nv1)
-            return jax.vmap(one)(kk, pts, nv)
-        args: Tuple = (keys, stacked, n_valid)
-    else:
-        stacked = np.stack(features).astype(np.float32)    # (M, N, d)
+            def fit_batch(kk, pts, nv):
+                one = lambda kk1, p1, nv1: kmeans_fit(
+                    kk1, p1, k_eff, iters=iters, impl=impl, n_valid=nv1)
+                return jax.vmap(one)(kk, pts, nv)
+            args: Tuple = (keys, stacked, n_valid)
+        else:
+            stacked = np.stack(features).astype(np.float32)  # (M, N, d)
 
-        def fit_batch(kk, pts):
-            return jax.vmap(functools.partial(
-                kmeans_fit, k=k_eff, iters=iters, impl=impl))(kk, pts)
-        args = (keys, stacked)
+            def fit_batch(kk, pts):
+                return jax.vmap(functools.partial(
+                    kmeans_fit, k=k_eff, iters=iters, impl=impl))(kk, pts)
+            args = (keys, stacked)
 
-    mesh, axis, n_shards = resolve_batch_mesh(mesh, shard_axis)
-    fn = fit_batch
-    if mesh is not None:
-        fn = batch_shard_map(fit_batch, mesh, axis)
-        args, _ = pad_batch_rows(args, n_shards)
-    # deliberate AOT lower/compile: shapes and shard wrapping vary per
-    # call, a cached wrapper would not help
-    # lint-ok: call-time-jit (AOT compile, shapes vary per call)
-    compiled = jax.jit(fn).lower(*args).compile()
+        mesh, axis, n_shards = resolve_batch_mesh(mesh, shard_axis)
+        fn = fit_batch
+        if mesh is not None:
+            fn = batch_shard_map(fit_batch, mesh, axis)
+            args, _ = pad_batch_rows(args, n_shards)
+    with span("coreset.compile", clients=m, k=k_eff, iters=iters):
+        # deliberate AOT lower/compile: shapes and shard wrapping vary
+        # per call, a cached wrapper would not help
+        # lint-ok: call-time-jit (AOT compile, shapes vary per call)
+        compiled = jax.jit(fn).lower(*args).compile()
     t0 = time.perf_counter()
     cents, assign, sqd = jax.block_until_ready(compiled(*args))
     t_exec = time.perf_counter() - t0
-    cents, assign, sqd = (np.asarray(cents), np.asarray(assign),
-                          np.asarray(sqd))
-    local = [
-        ClientClustering(assign[i, :ns[i]].astype(np.int32),
-                         sqd[i, :ns[i]].astype(np.float32),
-                         rank_weights(assign[i, :ns[i]], sqd[i, :ns[i]],
-                                      k_eff),
-                         cents[i][:, :ds[i]])
-        for i in range(m)
-    ]
+    with span("coreset.weights", clients=m):
+        cents, assign, sqd = (np.asarray(cents), np.asarray(assign),
+                              np.asarray(sqd))
+        local = [
+            ClientClustering(assign[i, :ns[i]].astype(np.int32),
+                             sqd[i, :ns[i]].astype(np.float32),
+                             rank_weights(assign[i, :ns[i]],
+                                          sqd[i, :ns[i]], k_eff),
+                             cents[i][:, :ds[i]])
+            for i in range(m)
+        ]
     return local, t_exec, n_shards
 
 

@@ -188,8 +188,9 @@ def tree_mpsi(id_sets: Sequence[np.ndarray], *,
                               ALIGN_ALIASES))
     protocol, backend = options.protocol, options.psi_backend
     m = len(id_sets)
-    holdings: Dict[int, np.ndarray] = {i: canonical_ids(s) for i, s in
-                                       enumerate(id_sets)}
+    with span("align.canonical", parties=m):
+        holdings: Dict[int, np.ndarray] = {i: canonical_ids(s) for i, s in
+                                           enumerate(id_sets)}
     active = list(range(m))
     total_bytes = total_msgs = 0
     compute = 0.0
@@ -286,7 +287,8 @@ def path_mpsi(id_sets: Sequence[np.ndarray], *,
                               ALIGN_ALIASES))
     protocol, backend = options.protocol, options.psi_backend
     m = len(id_sets)
-    cur = canonical_ids(id_sets[0])
+    with span("align.canonical", parties=1):
+        cur = canonical_ids(id_sets[0])
     total_bytes = total_msgs = 0
     compute = 0.0
     per_round: List[float] = []
@@ -335,7 +337,8 @@ def star_mpsi(id_sets: Sequence[np.ndarray], *,
                               ALIGN_ALIASES))
     protocol, backend = options.protocol, options.psi_backend
     m = len(id_sets)
-    cur = canonical_ids(id_sets[center])
+    with span("align.canonical", parties=1):
+        cur = canonical_ids(id_sets[center])
     total_bytes = total_msgs = 0
     compute = 0.0
     center_busy = 0.0

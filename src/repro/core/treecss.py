@@ -107,7 +107,8 @@ def _align(partition: VerticalPartition, topology: str, *,
     engine speedups are visible in ``PipelineReport``."""
     n = partition.n_samples
     m = partition.n_clients
-    sets, _core = make_id_universe(m, n, align.overlap, seed=seed)
+    with span("align.ids", parties=m, rows=n):
+        sets, _core = make_id_universe(m, n, align.overlap, seed=seed)
     sp = span("align.mpsi", topology=topology, protocol=align.protocol,
               backend=align.psi_backend, n_clients=m, n_ids=n)
     t0 = now()

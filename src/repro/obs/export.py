@@ -1,6 +1,5 @@
-"""Trace export: Chrome trace-event JSON (Perfetto-loadable), JSONL
-event log, CSV summary — plus the schema validator the CI gate runs
-(DESIGN.md §10).
+"""Trace export: Chrome trace-event JSON (Perfetto-loadable), plus the
+schema validator the CI gate runs (DESIGN.md §10).
 
 Chrome trace format: ``{"traceEvents": [...]}`` with complete-duration
 events (``"ph": "X"``) — ``ts``/``dur`` in microseconds relative to the
@@ -21,13 +20,11 @@ gates the uploaded artifact.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import Any, Dict, List, Sequence, Union
 
-from repro.obs.metrics import _nearest_rank
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import Tracer
 
-__all__ = ["chrome_trace", "write_chrome_trace", "write_jsonl",
-           "write_csv_summary", "summarize", "validate_chrome_trace",
+__all__ = ["chrome_trace", "write_chrome_trace", "validate_chrome_trace",
            "TraceValidationError"]
 
 _REQUIRED = ("name", "ph", "ts", "dur", "pid", "tid")
@@ -73,55 +70,6 @@ def write_chrome_trace(tracer: Tracer, path: str) -> Dict[str, Any]:
         json.dump(doc, f)
         f.write("\n")
     return doc
-
-
-def write_jsonl(tracer: Tracer, path: str) -> int:
-    """One JSON object per finished span (seconds, absolute-epoch
-    relative) — the machine-greppable event log."""
-    spans = tracer.finished()
-    with open(path, "w") as f:
-        for sp in spans:
-            f.write(json.dumps({
-                "name": sp.name, "t0": sp.t0 - tracer.epoch,
-                "dur": sp.duration, "sid": sp.sid, "parent": sp.parent,
-                "depth": sp.depth,
-                "attrs": {k: _json_safe(v) for k, v in sp.attrs.items()},
-            }) + "\n")
-    return len(spans)
-
-
-def summarize(spans: Sequence[Span]) -> List[Dict[str, Any]]:
-    """Per-name aggregate rows: count, total/mean/p50/p99/max seconds.
-    Sorted by total descending — the per-stage breakdown table."""
-    groups: Dict[str, List[float]] = {}
-    for sp in spans:
-        groups.setdefault(sp.name, []).append(sp.duration)
-    rows = []
-    for name, durs in groups.items():
-        durs.sort()
-        total = float(sum(durs))
-        rows.append({
-            "name": name, "count": len(durs), "total_s": total,
-            "mean_s": total / len(durs),
-            "p50_s": _nearest_rank(durs, 50),
-            "p99_s": _nearest_rank(durs, 99),
-            "max_s": durs[-1],
-        })
-    rows.sort(key=lambda r: -r["total_s"])
-    return rows
-
-
-def write_csv_summary(tracer: Tracer, path: str) -> List[Dict[str, Any]]:
-    rows = summarize(tracer.finished())
-    keys = ["name", "count", "total_s", "mean_s", "p50_s", "p99_s",
-            "max_s"]
-    with open(path, "w") as f:
-        f.write(",".join(keys) + "\n")
-        for r in rows:
-            f.write(",".join(
-                f"{r[k]:.6f}" if isinstance(r[k], float) else str(r[k])
-                for k in keys) + "\n")
-    return rows
 
 
 # ------------------------------------------------------------ validation

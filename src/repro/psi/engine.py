@@ -133,6 +133,15 @@ def _host_key_rows(tag64_sorted: np.ndarray, origin: int,
     return kh, kl
 
 
+def _batch_counts(sides: Sequence[Sequence[np.ndarray]], rows: int,
+                  p: int) -> dict:
+    """The ``keys``/``slots`` attributes of an ``align.dispatch`` span,
+    from host shapes alone: the real keys of both sides of every pair,
+    and the key slots of the batch as dispatched (2 x padded rows x P)."""
+    return {"keys": sum(len(s) for side in sides for s in side),
+            "slots": 2 * rows * p}
+
+
 def _mask_pad(kh, kl, n, pad):
     pos = jnp.arange(kh.shape[0], dtype=jnp.int32)
     return (jnp.where(pos < n, kh, np.uint32(pad[0])),
@@ -270,28 +279,32 @@ def _host_sorted_merge(r_tags64: Sequence[np.ndarray],
     each pair's u64 tags, pack the padded key-lane batch, run the merge
     dispatch, and recover ids from (sel, rank)."""
     b = len(r_tags64)
-    a_kh = np.empty((b, p), np.uint32)
-    a_kl = np.empty((b, p), np.uint32)
-    b_kh = np.empty((b, p), np.uint32)
-    b_kl = np.empty((b, p), np.uint32)
-    ids_by_tag: List[np.ndarray] = []
-    with span("align.host_sort", pairs=b, p=p):
-        for i in range(b):
-            order = np.argsort(r_tags64[i])
-            ids_by_tag.append(np.asarray(receiver_ids[i], np.int64)[order])
-            a_kh[i], a_kl[i] = _host_key_rows(r_tags64[i][order], 1, PAD_A,
-                                              p)
-            b_kh[i], b_kl[i] = _host_key_rows(np.sort(s_tags64[i]), 0,
-                                              PAD_B, p)
-    args, _ = pad_batch_rows((a_kh, a_kl, b_kh, b_kl), n_shards)
+    with span("align.pack", pairs=b, p=p):
+        a_kh = np.empty((b, p), np.uint32)
+        a_kl = np.empty((b, p), np.uint32)
+        b_kh = np.empty((b, p), np.uint32)
+        b_kl = np.empty((b, p), np.uint32)
+        ids_by_tag: List[np.ndarray] = []
+        with span("align.host_sort", pairs=b, p=p):
+            for i in range(b):
+                order = np.argsort(r_tags64[i])
+                ids_by_tag.append(
+                    np.asarray(receiver_ids[i], np.int64)[order])
+                a_kh[i], a_kl[i] = _host_key_rows(r_tags64[i][order], 1,
+                                                  PAD_A, p)
+                b_kh[i], b_kl[i] = _host_key_rows(np.sort(s_tags64[i]), 0,
+                                                  PAD_B, p)
+        args, _ = pad_batch_rows((a_kh, a_kl, b_kh, b_kl), n_shards)
     with span("align.dispatch", kind="merge", pairs=b, p=p,
-              shards=n_shards):
+              shards=n_shards,
+              **_batch_counts((r_tags64, s_tags64), len(args[0]), p)):
         sel_rank = jax.block_until_ready(
             _dispatch("merge", key)(*args))
-    sel = np.asarray(sel_rank[0])[:b].astype(bool)
-    rank = np.asarray(sel_rank[1])[:b]
-    return [np.sort(ids_by_tag[i][rank[i][sel[i]] - 1])
-            for i in range(b)]
+    with span("align.recover", pairs=b):
+        sel = np.asarray(sel_rank[0])[:b].astype(bool)
+        rank = np.asarray(sel_rank[1])[:b]
+        return [np.sort(ids_by_tag[i][rank[i][sel[i]] - 1])
+                for i in range(b)]
 
 
 def oprf_round(sender_sets: Sequence[np.ndarray],
@@ -320,41 +333,45 @@ def oprf_round(sender_sets: Sequence[np.ndarray],
     key, n_shards = dispatch_key(options)
     p = next_pow2(max(max((len(s) for s in sender_sets), default=0),
                       max((len(r) for r in receiver_sets), default=0), 1))
-    s_hi, s_lo, s_n = _pack(sender_sets, p)
-    r_hi, r_lo, r_n = _pack(receiver_sets, p)
-    seed_arr = np.asarray(seeds, np.uint32).reshape(b, 2)
+    with span("align.pack", pairs=b, p=p):
+        s_hi, s_lo, s_n = _pack(sender_sets, p)
+        r_hi, r_lo, r_n = _pack(receiver_sets, p)
+        seed_arr = np.asarray(seeds, np.uint32).reshape(b, 2)
+        lanes = ((r_hi, r_lo, r_n, s_hi, s_lo, s_n, seed_arr)
+                 if sort == "device" else (r_hi, r_lo, s_hi, s_lo, seed_arr))
+        args, _ = pad_batch_rows(lanes, n_shards)
+    bp = args[0].shape[0]
+    counts = _batch_counts((sender_sets, receiver_sets), bp, p)
 
     if sort == "device":
-        args, _ = pad_batch_rows(
-            (r_hi, r_lo, r_n, s_hi, s_lo, s_n, seed_arr), n_shards)
-        _warm("single", args[0].shape[0], p, key)
+        _warm("single", bp, p, key)
         fn = _dispatch("single", key)
         t0 = time.perf_counter()
         with span("align.dispatch", kind="single", pairs=b, p=p,
-                  shards=n_shards):
+                  shards=n_shards, **counts):
             out = jax.block_until_ready(fn(*args))
-        sel = np.asarray(out[0])[:b].astype(bool)
-        ids = (np.asarray(out[1], np.uint64)[:b] << np.uint64(32)) \
-            | np.asarray(out[2], np.uint64)[:b]
-        inters = [np.sort(ids[i][sel[i]].astype(np.int64))
-                  for i in range(b)]
+        with span("align.recover", pairs=b):
+            sel = np.asarray(out[0])[:b].astype(bool)
+            ids = (np.asarray(out[1], np.uint64)[:b] << np.uint64(32)) \
+                | np.asarray(out[2], np.uint64)[:b]
+            inters = [np.sort(ids[i][sel[i]].astype(np.int64))
+                      for i in range(b)]
         return EngineRound(inters, time.perf_counter() - t0, 1,
                            shards=n_shards)
 
-    args, _ = pad_batch_rows((r_hi, r_lo, s_hi, s_lo, seed_arr), n_shards)
-    bp = args[0].shape[0]
     _warm("prf", bp, p, key)
     _warm("merge", bp, p, key)
     fn = _dispatch("prf", key)
     t0 = time.perf_counter()
     with span("align.dispatch", kind="prf", pairs=b, p=p,
-              shards=n_shards):
+              shards=n_shards, **counts):
         tags = jax.block_until_ready(fn(*args))
-    r_th, r_tl, s_th, s_tl = (np.asarray(t) for t in tags)
-    join = lambda th, tl, n: ((th[:n].astype(np.uint64) << np.uint64(32))
-                              | tl[:n])
-    r_tags = [join(r_th[i], r_tl[i], int(r_n[i])) for i in range(b)]
-    s_tags = [join(s_th[i], s_tl[i], int(s_n[i])) for i in range(b)]
+    with span("align.recover", pairs=b):
+        r_th, r_tl, s_th, s_tl = (np.asarray(t) for t in tags)
+        join = lambda th, tl, n: ((th[:n].astype(np.uint64)
+                                   << np.uint64(32)) | tl[:n])
+        r_tags = [join(r_th[i], r_tl[i], int(r_n[i])) for i in range(b)]
+        s_tags = [join(s_th[i], s_tl[i], int(s_n[i])) for i in range(b)]
     inters = _host_sorted_merge(r_tags, receiver_sets, s_tags, p, key,
                                 n_shards)
     return EngineRound(inters, time.perf_counter() - t0, 2,
@@ -408,18 +425,21 @@ def union_merge(a_tags64: np.ndarray, b_tags64: np.ndarray, *,
     options = options or AlignOptions()
     key, n_shards = dispatch_key(options)
     p = next_pow2(max(len(a_tags64), len(b_tags64), 1))
-    a_kh, a_kl = _host_key_rows(np.asarray(a_tags64, np.uint64), 1,
-                                PAD_A, p)
-    b_kh, b_kl = _host_key_rows(np.asarray(b_tags64, np.uint64), 0,
-                                PAD_B, p)
-    args, _ = pad_batch_rows((a_kh[None], a_kl[None], b_kh[None],
-                              b_kl[None]), n_shards)
+    with span("align.pack", pairs=1, p=p):
+        a_kh, a_kl = _host_key_rows(np.asarray(a_tags64, np.uint64), 1,
+                                    PAD_A, p)
+        b_kh, b_kl = _host_key_rows(np.asarray(b_tags64, np.uint64), 0,
+                                    PAD_B, p)
+        args, _ = pad_batch_rows((a_kh[None], a_kl[None], b_kh[None],
+                                  b_kl[None]), n_shards)
     _warm("union", args[0].shape[0], p, key)
     with span("align.dispatch", kind="union", pairs=1, p=p,
-              shards=n_shards):
+              shards=n_shards,
+              **_batch_counts(((a_tags64,), (b_tags64,)), len(args[0]), p)):
         out = jax.block_until_ready(_dispatch("union", key)(*args))
-    m_kh = np.asarray(out[0])[0]
-    m_kl = np.asarray(out[1])[0]
-    merged = (m_kh.astype(np.uint64) << np.uint64(32)) \
-        | m_kl.astype(np.uint64)
-    return merged[m_kh < np.uint32(0x80000000)]
+    with span("align.recover", pairs=1):
+        m_kh = np.asarray(out[0])[0]
+        m_kl = np.asarray(out[1])[0]
+        merged = (m_kh.astype(np.uint64) << np.uint64(32)) \
+            | m_kl.astype(np.uint64)
+        return merged[m_kh < np.uint32(0x80000000)]

@@ -612,84 +612,86 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
     from repro.config import EngineOptions
     from repro.core import splitnn as models
 
-    options = options or EngineOptions()
-    bottom_impl = options.bottom_impl
-    block_b = options.block_b
-    fuse_gather = options.fuse_gather
-    quant = options.quant
+    with span("train.setup", engine="scan", rows=partition.n_samples,
+              clients=partition.n_clients):
+        options = options or EngineOptions()
+        bottom_impl = options.bottom_impl
+        block_b = options.block_b
+        fuse_gather = options.fuse_gather
+        quant = options.quant
 
-    n = partition.n_samples
-    m = partition.n_clients
-    feature_dims = [f.shape[1] for f in partition.client_features]
-    d_max = max(feature_dims)
+        n = partition.n_samples
+        m = partition.n_clients
+        feature_dims = [f.shape[1] for f in partition.client_features]
+        d_max = max(feature_dims)
 
-    mesh, data_axis, n_data, model_axis, n_model = resolve_train_mesh(
-        options.mesh, options.shard_axis)
+        mesh, data_axis, n_data, model_axis, n_model = resolve_train_mesh(
+            options.mesh, options.shard_axis)
 
-    use_slab = bottom_impl in ("ref", "pallas")
-    if n_model > 1 and not use_slab:
-        raise ValueError(
-            "model-axis sharding needs the slab bottom path "
-            "(bottom_impl='ref'|'pallas'), not 'loop'")
-    quant = resolve_quant(quant)
-    if quant is not None and not use_slab:
-        raise ValueError(
-            "quantized activations need the slab bottom path "
-            "(bottom_impl='ref'|'pallas'), not 'loop'")
+        use_slab = bottom_impl in ("ref", "pallas")
+        if n_model > 1 and not use_slab:
+            raise ValueError(
+                "model-axis sharding needs the slab bottom path "
+                "(bottom_impl='ref'|'pallas'), not 'loop'")
+        quant = resolve_quant(quant)
+        if quant is not None and not use_slab:
+            raise ValueError(
+                "quantized activations need the slab bottom path "
+                "(bottom_impl='ref'|'pallas'), not 'loop'")
 
-    prog = make_epoch_fn(cfg, tuple(int(d) for d in feature_dims), mesh,
-                         data_axis, model_axis, n_data, n_model,
-                         bottom_impl, int(block_b), bool(fuse_gather),
-                         quant)
-    m_pad = prog.m_pad                           # dummy clients (§8)
+        prog = make_epoch_fn(cfg, tuple(int(d) for d in feature_dims), mesh,
+                             data_axis, model_axis, n_data, n_model,
+                             bottom_impl, int(block_b), bool(fuse_gather),
+                             quant)
+        m_pad = prog.m_pad                           # dummy clients (§8)
 
-    def fresh_params():
-        zoo = models.init_splitnn(cfg, feature_dims)
-        return pack_slab_params(zoo, d_max, m_pad) if use_slab else zoo
+        def fresh_params():
+            zoo = models.init_splitnn(cfg, feature_dims)
+            return pack_slab_params(zoo, d_max, m_pad) if use_slab else zoo
 
-    params = fresh_params()
-    opt = adam_init(params)
+        params = fresh_params()
+        opt = adam_init(params)
 
-    y_np = partition.labels
-    y_all = jnp.asarray(y_np, jnp.float32 if cfg.n_classes == 0
-                        else jnp.int32)
-    w_np = (np.asarray(sample_weights, np.float32)
-            if sample_weights is not None else np.ones(n, np.float32))
-    w_eff = jnp.asarray(w_np)
+        y_np = partition.labels
+        y_all = jnp.asarray(y_np, jnp.float32 if cfg.n_classes == 0
+                            else jnp.int32)
+        w_np = (np.asarray(sample_weights, np.float32)
+                if sample_weights is not None else np.ones(n, np.float32))
+        w_eff = jnp.asarray(w_np)
 
-    if use_slab:
-        slab = pack_slab(partition.client_features, m_pad)
-        if prog.d_eff > d_max:
-            # align the slab's d to the kernel lane width ONCE, here,
-            # so the per-step gather-fused pass hands the loop-invariant
-            # slab straight to the kernel instead of re-padding it every
-            # scan step (pad_bottom_blocks_gather no-ops on aligned f32;
-            # zero columns meet zero weight rows, values unchanged)
-            slab = np.concatenate(
-                [slab, np.zeros(slab.shape[:2] + (prog.d_eff - d_max,),
-                                np.float32)], axis=2)
-        data: Tuple = (jnp.asarray(slab),)
-    else:
-        data = tuple(jnp.asarray(f, jnp.float32)
-                     for f in partition.client_features)
-    arrays = data + (y_all, w_eff)
+        if use_slab:
+            slab = pack_slab(partition.client_features, m_pad)
+            if prog.d_eff > d_max:
+                # align the slab's d to the kernel lane width ONCE, here,
+                # so the per-step gather-fused pass hands the loop-invariant
+                # slab straight to the kernel instead of re-padding it every
+                # scan step (pad_bottom_blocks_gather no-ops on aligned f32;
+                # zero columns meet zero weight rows, values unchanged)
+                slab = np.concatenate(
+                    [slab, np.zeros(slab.shape[:2] + (prog.d_eff - d_max,),
+                                    np.float32)], axis=2)
+            data: Tuple = (jnp.asarray(slab),)
+        else:
+            data = tuple(jnp.asarray(f, jnp.float32)
+                         for f in partition.client_features)
+        arrays = data + (y_all, w_eff)
 
-    bs = min(cfg.batch_size, n)
-    steps_per_epoch = -(-n // bs)
-    padded_bs = padded_rows(bs, n_data)
+        bs = min(cfg.batch_size, n)
+        steps_per_epoch = -(-n // bs)
+        padded_bs = padded_rows(bs, n_data)
 
-    jitted = prog.jitted
-    arrays = prog.pin_arrays(arrays)
+        jitted = prog.jitted
+        arrays = prog.pin_arrays(arrays)
 
-    # compile + warm up OUTSIDE the timed region (the warm-up consumes
-    # the donated carry, so re-init to the identical seeded state), then
-    # keep every timed call signature-stable: committed carry in,
-    # committed carry out — no mid-loop recompiles.  ``prog`` is cached:
-    # a repeated call with the same (config, layout, mesh) reuses the
-    # compiled executable and the warm-up is a cheap re-dispatch.
-    idx0, mask0 = epoch_schedule(np.arange(n), n, bs, steps_per_epoch,
-                                 padded_bs)
-    params, opt = prog.pin_carry(params, opt)
+        # compile + warm up OUTSIDE the timed region (the warm-up consumes
+        # the donated carry, so re-init to the identical seeded state), then
+        # keep every timed call signature-stable: committed carry in,
+        # committed carry out — no mid-loop recompiles.  ``prog`` is cached:
+        # a repeated call with the same (config, layout, mesh) reuses the
+        # compiled executable and the warm-up is a cheap re-dispatch.
+        idx0, mask0 = epoch_schedule(np.arange(n), n, bs, steps_per_epoch,
+                                     padded_bs)
+        params, opt = prog.pin_carry(params, opt)
     with span("train.compile", engine="scan", bottom_impl=bottom_impl,
               steps_per_epoch=steps_per_epoch, padded_batch=padded_bs,
               mesh=(n_data, n_model), fused_gather=use_slab and fuse_gather):

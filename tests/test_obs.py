@@ -13,11 +13,10 @@ from conftest import make_cls_partition
 from repro.core import SplitNNConfig, run_pipeline
 from repro.core import splitnn as models
 from repro.core.splitnn import train_splitnn
-from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, Span,
+from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry,
                        StatsMixin, TraceValidationError, Tracer,
-                       chrome_trace, span, summarize, use_tracer,
-                       validate_chrome_trace, write_chrome_trace,
-                       write_csv_summary, write_jsonl)
+                       chrome_trace, span, use_tracer,
+                       validate_chrome_trace, write_chrome_trace)
 from repro.obs.trace import NULL_SPAN, active_tracer
 from repro.serve.vfl import (ScoreRequest, ServeStats, VFLScoringEngine,
                              simulate_trace)
@@ -154,15 +153,12 @@ def test_export_files_and_view_cli(tmp_path):
     from repro.obs.view import view
     tracer = _toy_tracer()
     trace_path = str(tmp_path / "trace.json")
-    write_chrome_trace(tracer, trace_path)
-    assert write_jsonl(tracer, str(tmp_path / "trace.jsonl")) == 5
-    lines = [json.loads(l) for l in
-             open(tmp_path / "trace.jsonl").read().splitlines()]
-    assert {l["name"] for l in lines} == {
+    doc = write_chrome_trace(tracer, trace_path)
+    with open(trace_path) as f:
+        assert json.load(f) == json.loads(json.dumps(doc))
+    assert {e["name"] for e in doc["traceEvents"]} == {
         "pipeline.run", "align.step", "coreset.step", "train.step",
         "serve.step"}
-    rows = write_csv_summary(tracer, str(tmp_path / "trace.csv"))
-    assert rows[0]["name"] == "pipeline.run"       # largest total first
     # the CI gate: view() exits 0 on a good trace, 1 on schema violations
     assert view(trace_path, require_cats=("align", "serve")) == 0
     assert view(trace_path, require_cats=("nonexistent",)) == 1
@@ -170,14 +166,6 @@ def test_export_files_and_view_cli(tmp_path):
     with open(bad_path, "w") as f:
         json.dump({"traceEvents": [{"name": "x"}]}, f)
     assert view(bad_path) == 1
-
-
-def test_summarize_percentiles():
-    spans = [Span(name="train.epoch", t0=0.0, t1=float(i + 1))
-             for i in range(4)]
-    (row,) = summarize(spans)
-    assert row["count"] == 4 and row["total_s"] == 10.0
-    assert row["p50_s"] == 2.0 and row["max_s"] == 4.0
 
 
 # ------------------------------------------------------------ registry
@@ -292,10 +280,33 @@ def _train(tracer):
     return rep
 
 
-def test_tracing_leaves_engine_contract_unchanged():
+def _align(tracer, monkeypatch):
+    """A tiny device-backend Tree-MPSI; returns its stats and the number
+    of blocking host syncs the PSI engine made."""
+    import jax
+
+    from repro.config import AlignOptions
+    from repro.core.mpsi import tree_mpsi
+    from repro.data.synthetic import make_id_universe
+
+    sets, _ = make_id_universe(5, 300, 0.7, seed=4)
+    syncs = []
+    block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: syncs.append(1) or block(x))
+    with use_tracer(tracer):
+        st = tree_mpsi(sets, options=AlignOptions(
+            protocol="oprf", psi_backend="device", impl="ref"))
+    monkeypatch.undo()
+    return st, len(syncs)
+
+
+def test_tracing_leaves_engine_contract_unchanged(monkeypatch):
     """The scan engine's ONE-dispatch + ONE-host-sync-per-epoch contract
     holds bit-for-bit with tracing on, and the traced run's span counts
-    line up with the counters."""
+    line up with the counters; the set-up, packing and recovery spans
+    leave the training and PSI engines' dispatches and host syncs as
+    they are."""
     base = _train(None)
     tracer = Tracer()
     traced = _train(tracer)
@@ -306,9 +317,20 @@ def test_tracing_leaves_engine_contract_unchanged():
     epochs = tracer.by_name("train.epoch")
     assert len(epochs) == traced.epochs
     assert len(tracer.by_name("train.compile")) == 1
+    assert len(tracer.by_name("train.setup")) == 1
     # per-epoch attrs carry the modeled comm volume and the loss
     assert all(s.attrs["comm_bytes"] > 0 and "loss" in s.attrs
                for s in epochs)
+
+    _align(None, monkeypatch)            # every bucket compiled once
+    st0, syncs0 = _align(None, monkeypatch)
+    tracer = Tracer()
+    st1, syncs1 = _align(tracer, monkeypatch)
+    assert syncs0 == syncs1 > 0
+    assert st0.device_dispatches == st1.device_dispatches == st1.rounds
+    np.testing.assert_array_equal(st0.intersection, st1.intersection)
+    assert len(tracer.by_name("align.dispatch")) == syncs1
+    assert len(tracer.by_name("align.recover")) == syncs1
 
 
 def test_tracing_leaves_serve_counters_unchanged():
