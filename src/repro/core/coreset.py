@@ -186,6 +186,48 @@ def clients_batchable(features: Sequence[np.ndarray], *,
     return min_n >= 1 and (clusters is None or min_n >= clusters)
 
 
+def _fit_batch(keys, points, *, fit, k: int, iters: int, impl: str):
+    """Same-shape clients: one vmap'd ``fit`` over (M, N, d)."""
+    return jax.vmap(functools.partial(
+        fit, k=k, iters=iters, impl=impl))(keys, points)
+
+
+def _fit_batch_ragged(keys, points, n_valid, *, fit, k: int, iters: int,
+                      impl: str):
+    """Ragged clients: rows at and past each client's ``n_valid`` are
+    zero padding, masked inside ``fit``."""
+    one = lambda kk, p, nv: fit(kk, p, k, iters=iters, impl=impl,
+                                n_valid=nv)
+    return jax.vmap(one)(keys, points, n_valid)
+
+
+@functools.lru_cache(maxsize=8)
+def _fit_program(fit, ragged: bool, k: int, iters: int, impl: str, mesh,
+                 axis: Optional[str],
+                 arg_specs: Tuple[Tuple[Tuple[int, ...], np.dtype], ...]):
+    """The AOT-compiled batched k-means program, one per (per-client
+    ``fit`` function, layout, k, iters, impl, resolved mesh/axis,
+    argument shapes and dtypes): every key is hashable, so repeated
+    ``cluster_coreset`` calls on the same deployment reuse one
+    executable instead of lowering and compiling it again, and a
+    different ``fit`` never reuses another's program.  Bounded at 8 (a
+    process sees one or two layouts); ``clear_fit_cache`` releases the
+    executables and the Mesh objects their keys pin."""
+    body = _fit_batch_ragged if ragged else _fit_batch
+    fn = functools.partial(body, fit=fit, k=k, iters=iters, impl=impl)
+    if mesh is not None:
+        fn = batch_shard_map(fn, mesh, axis)
+    specs = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in arg_specs]
+    return jax.jit(fn).lower(*specs).compile()
+
+
+def clear_fit_cache() -> None:
+    """Drop every cached batched k-means executable (and the Mesh
+    objects their keys pin); ``repro.train.vfl.clear_program_caches``
+    calls it, next to the training-side caches."""
+    _fit_program.cache_clear()
+
+
 def _batched_local_clusterings(features: Sequence[np.ndarray], k: int, *,
                                seed: int, iters: int, impl: str,
                                mesh=None,
@@ -212,9 +254,11 @@ def _batched_local_clusterings(features: Sequence[np.ndarray], k: int, *,
     the per-client program is unchanged, so results stay byte-identical
     to the single-device batch.
 
-    Returns (clusterings, seconds, n_shards) where seconds excludes XLA
-    compilation (the program is AOT-compiled before the timed region,
-    mirroring the warm-jit protocol the sequential path relies on).
+    The program comes from ``_fit_program``: the first call with a
+    given key compiles it, later calls look it up (the
+    ``coreset.compile`` span covers the lookup and records
+    ``cache_hit``).  Returns (clusterings, seconds, n_shards) where
+    seconds excludes that lookup and any compile.
     """
     m = len(features)
     ns = [int(f.shape[0]) for f in features]
@@ -230,31 +274,21 @@ def _batched_local_clusterings(features: Sequence[np.ndarray], k: int, *,
             stacked = np.zeros((m, n_max, d_max), np.float32)
             for i, f in enumerate(features):
                 stacked[i, :ns[i], :ds[i]] = f
-            n_valid = np.asarray(ns, np.int32)
-
-            def fit_batch(kk, pts, nv):
-                one = lambda kk1, p1, nv1: kmeans_fit(
-                    kk1, p1, k_eff, iters=iters, impl=impl, n_valid=nv1)
-                return jax.vmap(one)(kk, pts, nv)
-            args: Tuple = (keys, stacked, n_valid)
+            args: Sequence[np.ndarray] = (keys, stacked,
+                                          np.asarray(ns, np.int32))
         else:
-            stacked = np.stack(features).astype(np.float32)  # (M, N, d)
-
-            def fit_batch(kk, pts):
-                return jax.vmap(functools.partial(
-                    kmeans_fit, k=k_eff, iters=iters, impl=impl))(kk, pts)
-            args = (keys, stacked)
-
+            args = (keys, np.stack(features).astype(np.float32))
         mesh, axis, n_shards = resolve_batch_mesh(mesh, shard_axis)
-        fn = fit_batch
         if mesh is not None:
-            fn = batch_shard_map(fit_batch, mesh, axis)
             args, _ = pad_batch_rows(args, n_shards)
-    with span("coreset.compile", clients=m, k=k_eff, iters=iters):
-        # deliberate AOT lower/compile: shapes and shard wrapping vary
-        # per call, a cached wrapper would not help
-        # lint-ok: call-time-jit (AOT compile, shapes vary per call)
-        compiled = jax.jit(fn).lower(*args).compile()
+    with span("coreset.compile", clients=m, k=k_eff,
+              iters=iters) as compile_sp:
+        hits = _fit_program.cache_info().hits
+        compiled = _fit_program(kmeans_fit, ragged, k_eff, iters, impl,
+                                mesh, axis,
+                                tuple((a.shape, a.dtype) for a in args))
+        compile_sp.set(
+            cache_hit=int(_fit_program.cache_info().hits > hits))
     t0 = time.perf_counter()
     cents, assign, sqd = jax.block_until_ready(compiled(*args))
     t_exec = time.perf_counter() - t0

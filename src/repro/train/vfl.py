@@ -775,14 +775,17 @@ def _loop_step_fn(cfg):
 
 
 def clear_program_caches() -> None:
-    """Drop every cached jitted training/scoring program (and the Mesh
-    objects the epoch-program keys pin).  Tests that build transient
-    meshes call this so device meshes aren't held for process lifetime;
-    the paired PSI-side hook is ``repro.psi.engine.clear_dispatch_cache``.
+    """Drop every cached jitted training/scoring program and the
+    coreset's batched k-means executables (and the Mesh objects their
+    keys pin).  Tests that build transient meshes call this so device
+    meshes aren't held for process lifetime; the paired PSI-side hook is
+    ``repro.psi.engine.clear_dispatch_cache``.
     """
+    from repro.core.coreset import clear_fit_cache
     _score_step_fn.cache_clear()
     make_epoch_fn.cache_clear()
     _loop_step_fn.cache_clear()
+    clear_fit_cache()
 
 
 def train_loop(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,

@@ -1,4 +1,10 @@
 """Cluster-Coreset: weighting formula, CT grouping, selection invariants."""
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 try:
@@ -9,6 +15,10 @@ except ImportError:  # container image has no hypothesis
 from conftest import make_cls_partition
 from repro.core.coreset import (ClientClustering, cluster_coreset,
                                 local_cluster_weights, select_coreset)
+from repro.obs import Tracer, use_tracer
+from repro.train.vfl import clear_program_caches
+
+TESTS = Path(__file__).resolve().parent
 
 
 def test_local_weight_formula():
@@ -164,3 +174,120 @@ def test_property_selection_is_deterministic_partition(n, k, seed):
     assert np.allclose(r1.weights, r2.weights)
     # weights bounded by number of clients (each local weight ≤ 1)
     assert np.all(r1.weights <= part.n_clients + 1e-6)
+
+
+def _traced_coreset(part, k, **kw):
+    """``cluster_coreset`` under a fresh tracer: (result, the
+    ``coreset.compile`` span's ``cache_hit``)."""
+    tracer = Tracer()
+    with use_tracer(tracer):
+        res = cluster_coreset(part, k, **kw)
+    (sp,) = tracer.by_name("coreset.compile")
+    return res, sp.attrs["cache_hit"]
+
+
+def test_second_call_reuses_the_compiled_fit():
+    """The batched k-means program is compiled once per key: a second
+    call on the same partition triggers no backend compile, its
+    ``coreset.compile`` span reports the hit, and its selection is
+    bitwise that of a call made after the caches were dropped."""
+    from jax import monitoring
+
+    part = make_cls_partition(n=300, d=12, clients=3, seed=5)
+    clear_program_caches()
+    compiles = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        first, hit1 = _traced_coreset(part, 5, seed=2)
+        n_first = len(compiles)
+        second, hit2 = _traced_coreset(part, 5, seed=2)
+        n_second = len(compiles) - n_first
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert n_first > 0 and n_second == 0
+    assert (hit1, hit2) == (0, 1)
+    clear_program_caches()
+    fresh, hit3 = _traced_coreset(part, 5, seed=2)
+    assert hit3 == 0
+    for res in (first, second):
+        assert res.batched
+        assert np.array_equal(res.indices, fresh.indices)
+        assert res.weights.tobytes() == fresh.weights.tobytes()
+
+
+_MESH_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json
+import numpy as np
+from conftest import make_cls_partition
+from repro.core.coreset import _fit_program, clear_fit_cache, cluster_coreset
+from repro.launch.mesh import make_data_mesh
+
+# two clients on two devices: the sharded batch needs no row padding, so
+# both runs lower the same argument shapes
+part = make_cls_partition(n=240, d=8, clients=2, seed=3)
+clear_fit_cache()
+base = cluster_coreset(part, 4, seed=1)
+shrd = cluster_coreset(part, 4, seed=1, mesh=make_data_mesh())
+same = (np.array_equal(base.indices, shrd.indices)
+        and base.weights.tobytes() == shrd.weights.tobytes()
+        and all(b.assign.tobytes() == s.assign.tobytes()
+                and b.sq_dist.tobytes() == s.sq_dist.tobytes()
+                and b.centroids.tobytes() == s.centroids.tobytes()
+                for b, s in zip(base.local, shrd.local)))
+print("RESULT" + json.dumps({"shards": [base.shards, shrd.shards],
+                             "misses": _fit_program.cache_info().misses,
+                             "same": same}))
+"""
+
+
+def _mesh_case():
+    """A mesh and a no-mesh fit on the same shapes, in a subprocess with
+    two virtual CPU devices (the device count is fixed at JAX start)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [str(TESTS.parent / "src"), str(TESTS)]))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
+                         cwd=TESTS.parent, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("RESULT")]
+    r = json.loads(line[0][len("RESULT"):])
+    assert r["shards"] == [1, 2]
+    assert r["misses"] == 2         # no shared executable
+    assert r["same"]                # and byte-identical results
+
+
+# (first call, second call): make_cls_partition kwargs, k, kmeans_impl
+_KEY_CASES = {
+    "k": ((dict(d=12), 4, "ref"), (dict(d=12), 5, "ref")),
+    "ragged": ((dict(d=12), 4, "ref"), (dict(d=11), 4, "ref")),
+    "impl": ((dict(d=12), 4, "ref"), (dict(d=12), 4, "pallas")),
+}
+
+
+@pytest.mark.parametrize("case", [*_KEY_CASES, "mesh"])
+def test_fit_cache_key_separates(case):
+    """A different k, a ragged against a same-shape partition, the ref
+    against the pallas impl, and a mesh against no mesh each compile
+    their own program instead of reusing another key's."""
+    if case == "mesh":
+        _mesh_case()
+        return
+    from repro.core.coreset import _fit_program
+
+    clear_program_caches()
+    hits = []
+    for part_kw, k, impl in _KEY_CASES[case]:
+        part = make_cls_partition(n=200, clients=3, seed=7, **part_kw)
+        res, hit = _traced_coreset(part, k, seed=0, kmeans_impl=impl)
+        assert res.batched
+        hits.append(hit)
+    assert hits == [0, 0]
+    assert _fit_program.cache_info().currsize == 2
