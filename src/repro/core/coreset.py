@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -111,6 +112,25 @@ def _ct_keys(assigns: Sequence[np.ndarray]) -> np.ndarray:
     return np.stack(assigns, axis=1)
 
 
+def _group_ids(keys: np.ndarray) -> np.ndarray:
+    """``np.unique(keys, axis=0, return_inverse=True)[1]`` for an (N, C)
+    integer key matrix, through one int64 code per row: each column,
+    less its least value, is a digit of a mixed radix with the first
+    column most significant, so the codes sort as the rows do.  A 1-D
+    unique is ~25x faster than the row-wise one at 250K rows.  Keys too
+    wide for one int64 take the row-wise unique."""
+    if keys.shape[0] == 0:
+        return np.zeros(0, np.int64)
+    lo = keys.min(axis=0)
+    spans = [int(v) + 1 for v in keys.max(axis=0) - lo]
+    if math.prod(spans) >= 2 ** 63:
+        return np.unique(keys, axis=0, return_inverse=True)[1].reshape(-1)
+    code = np.zeros(keys.shape[0], np.int64)
+    for col, radix in enumerate(spans):
+        code = code * radix + (keys[:, col] - lo[col])
+    return np.unique(code, return_inverse=True)[1].reshape(-1)
+
+
 def select_coreset(local: Sequence[ClientClustering], labels: np.ndarray, *,
                    regression_bins: int = 16) -> Tuple[np.ndarray, np.ndarray,
                                                        int]:
@@ -118,6 +138,7 @@ def select_coreset(local: Sequence[ClientClustering], labels: np.ndarray, *,
 
     Regression labels (float) are quantile-binned so "split S_ct^j by label"
     stays meaningful — the paper trains LinearReg with the same machinery.
+    Spans: ``coreset.bin`` (regression only) and ``coreset.group``.
     """
     cts = _ct_keys([c.assign for c in local])                  # (N, M)
     ed = np.stack([np.sqrt(np.maximum(c.sq_dist, 0.0)) for c in local],
@@ -125,22 +146,27 @@ def select_coreset(local: Sequence[ClientClustering], labels: np.ndarray, *,
     w = np.stack([c.weight for c in local], axis=1)            # (N, M)
 
     if np.issubdtype(labels.dtype, np.floating):
-        qs = np.quantile(labels, np.linspace(0, 1, regression_bins + 1)[1:-1])
-        lab = np.searchsorted(qs, labels).astype(np.int64)
+        with span("coreset.bin", rows=labels.shape[0], bins=regression_bins):
+            qs = np.quantile(labels,
+                             np.linspace(0, 1, regression_bins + 1)[1:-1])
+            lab = np.searchsorted(qs, labels).astype(np.int64)
     else:
         lab = labels.astype(np.int64)
 
-    keys = np.concatenate([cts, lab[:, None]], axis=1)         # (N, M+1)
-    _, group_ids = np.unique(keys, axis=0, return_inverse=True)
-    agg_ed = ed.sum(axis=1)
+    group_sp = span("coreset.group", rows=labels.shape[0])
+    with group_sp:
+        keys = np.concatenate([cts, lab[:, None]], axis=1)     # (N, M+1)
+        group_ids = _group_ids(keys)
+        agg_ed = ed.sum(axis=1)
 
-    n_groups = int(group_ids.max()) + 1 if group_ids.size else 0
-    # argmin aggregated distance per group
-    order = np.lexsort((agg_ed, group_ids))
-    first = np.ones(len(order), bool)
-    first[1:] = group_ids[order][1:] != group_ids[order][:-1]
-    chosen = np.sort(order[first])
-    weights = w[chosen].sum(axis=1)
+        n_groups = int(group_ids.max()) + 1 if group_ids.size else 0
+        # argmin aggregated distance per group
+        order = np.lexsort((agg_ed, group_ids))
+        first = np.ones(len(order), bool)
+        first[1:] = group_ids[order][1:] != group_ids[order][:-1]
+        chosen = np.sort(order[first])
+        weights = w[chosen].sum(axis=1)
+    group_sp.set(groups=n_groups, kept=int(chosen.shape[0]))
     return chosen.astype(np.int64), weights.astype(np.float32), n_groups
 
 

@@ -91,6 +91,35 @@ def _dense_forward(x, w, b, relu, impl, block_b, quant=None):
     return out[:, :n, :o]
 
 
+def take_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``x[:, idx, :]`` for an (M, N, d) slab, as one gather of whole
+    rows of its (M·N, d) view.  ``jnp.take(x, idx, axis=1)`` gathers
+    (M, 1, d) slices, which are not contiguous, so XLA on a TPU
+    relayouts the whole slab to make them so: a copy of every row per
+    call, where this reads only the B·M gathered ones."""
+    m, n, d = x.shape
+    rows = (jnp.arange(m, dtype=idx.dtype)[:, None] * n + idx).reshape(-1)
+    return jnp.take(x.reshape(m * n, d), rows, axis=0).reshape(
+        m, idx.shape[0], d)
+
+
+def gather_path(impl: str, n_rows: int, d: int, quant=None) -> str:
+    """The path ``splitnn_bottom(idx=...)`` takes over an (M, n_rows, d)
+    slab, under the name ``record_path`` counts it by: "gather_fused"
+    while one client's lane-padded slab fits ``GATHER_VMEM_BUDGET``
+    (always in interpret mode), "gather_fallback" (gather, then the
+    dense pass) past it, each prefixed "int8_" for the int8 twins; "ref"
+    for the jnp oracle, which always gathers first."""
+    if impl != "pallas":
+        return "ref"
+    kq = quant == "int8"
+    elem = 1 if kq else 4         # int8 slab: 4x the VMEM reach
+    fits = (interpret()
+            or n_rows * round_up(d, 128) * elem <= GATHER_VMEM_BUDGET)
+    return ("int8_gather" if kq else "gather") + (
+        "_fused" if fits else "_fallback")
+
+
 def _forward(x, w, b, relu, impl, block_b, idx=None, quant=None):
     if quant not in (None, "int8", "fp8"):
         raise ValueError(f"splitnn_bottom: unknown quant={quant!r}")
@@ -102,11 +131,9 @@ def _forward(x, w, b, relu, impl, block_b, idx=None, quant=None):
         return _dense_forward(x, w, b, relu, impl, block_b, kq)
     o = w.shape[2]
     if impl == "pallas":
-        dp = round_up(x.shape[2], 128)
-        elem = 1 if kq else 4     # int8 slab: 4x the VMEM reach
-        tag = "int8_gather" if kq else "gather"
-        if interpret() or x.shape[1] * dp * elem <= GATHER_VMEM_BUDGET:
-            record_path("splitnn_bottom", tag + "_fused")
+        path = gather_path(impl, x.shape[1], x.shape[2], kq)
+        if path.endswith("_fused"):
+            record_path("splitnn_bottom", path)
             idx_p, bb, bsz = pad_gather_idx(idx, block_b)
             xp, wp, bp = pad_bottom_blocks_gather(x, w, b)
             if kq:
@@ -123,13 +150,13 @@ def _forward(x, w, b, relu, impl, block_b, idx=None, quant=None):
                                                    relu=relu, block_b=bb,
                                                    interpret=interpret())
             return out[:, :bsz, :o]
-        record_path("splitnn_bottom", tag + "_fallback")
+        record_path("splitnn_bottom", path)
     # ref oracle (and the past-VMEM-budget fallback): gather, then the
     # dense pass — the bitwise contract the fused kernel must match
     # (per-row int8 scales make quantize-then-gather == gather-then-
     # quantize, row by row)
-    return _dense_forward(jnp.take(x, idx, axis=1), w, b, relu, impl,
-                          block_b, kq)
+    return _dense_forward(take_rows(x, idx), w, b, relu, impl, block_b,
+                          kq)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 7))
@@ -167,7 +194,7 @@ def _bwd(relu, impl, block_b, quant, res, g):
     del quant
     x, w, out, idx = res
     dpre = g * (out > 0) if relu else g                       # (M, B, o)
-    xg = x if idx is None else jnp.take(x, idx, axis=1)       # (M, B, d)
+    xg = x if idx is None else take_rows(x, idx)              # (M, B, d)
     xg = xg[..., :w.shape[1]]     # drop pre-padded zero columns (if any)
     dx = jax.lax.dot_general(                                 # (M, B, d)
         dpre, w, (((2,), (2,)), ((0,), (0,))),

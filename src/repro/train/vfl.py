@@ -372,6 +372,22 @@ def epoch_schedule(order: np.ndarray, n: int, bs: int, steps: int,
 # ------------------------------------------------------------ scan engine
 
 
+def bottom_path(bottom_impl: str, fuse_gather: bool, n: int, d: int,
+                quant: Optional[str] = None) -> str:
+    """Which bottom pass the epoch program runs over an (M, n, d) slab,
+    by the ``record_path`` name of ``splitnn_bottom``'s wrapper: the
+    gather-fused kernel or its past-budget fallback
+    (``gather_path``), "dense" (pallas, gather first), "ref" (the jnp
+    slab oracle) or "loop" (per-client matmuls)."""
+    from repro.kernels.splitnn_bottom.ops import gather_path
+
+    if bottom_impl not in ("ref", "pallas"):
+        return "loop"
+    if not fuse_gather:
+        return "dense" if bottom_impl == "pallas" else "ref"
+    return gather_path(bottom_impl, n, d, quant)
+
+
 @dataclasses.dataclass
 class EpochProgram:
     """One reusable compiled epoch-step program and the sharding layout
@@ -613,7 +629,7 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
     from repro.core import splitnn as models
 
     with span("train.setup", engine="scan", rows=partition.n_samples,
-              clients=partition.n_clients):
+              clients=partition.n_clients) as setup_sp:
         options = options or EngineOptions()
         bottom_impl = options.bottom_impl
         block_b = options.block_b
@@ -692,6 +708,8 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
         idx0, mask0 = epoch_schedule(np.arange(n), n, bs, steps_per_epoch,
                                      padded_bs)
         params, opt = prog.pin_carry(params, opt)
+        setup_sp.set(bottom_path=bottom_path(bottom_impl, fuse_gather,
+                                             n, prog.d_eff, quant))
     with span("train.compile", engine="scan", bottom_impl=bottom_impl,
               steps_per_epoch=steps_per_epoch, padded_batch=padded_bs,
               mesh=(n_data, n_model), fused_gather=use_slab and fuse_gather):

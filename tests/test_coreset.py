@@ -13,8 +13,9 @@ except ImportError:  # container image has no hypothesis
     from _propcheck import given, settings, strategies as st
 
 from conftest import make_cls_partition
-from repro.core.coreset import (ClientClustering, cluster_coreset,
-                                local_cluster_weights, select_coreset)
+from repro.core.coreset import (ClientClustering, _group_ids,
+                                cluster_coreset, local_cluster_weights,
+                                select_coreset)
 from repro.obs import Tracer, use_tracer
 from repro.train.vfl import clear_program_caches
 
@@ -291,3 +292,15 @@ def test_fit_cache_key_separates(case):
         hits.append(hit)
     assert hits == [0, 0]
     assert _fit_program.cache_info().currsize == 2
+
+
+@pytest.mark.parametrize("lo,hi,cols", [(0, 12, 4), (-3, 5, 2), (0, 7, 1),
+                                        (-2**40, 2**40, 3)])
+def test_group_ids_match_the_row_wise_unique(lo, hi, cols):
+    """The mixed-radix code numbers groups as ``np.unique(axis=0)``
+    does: negative digits, one column, and keys too wide for one int64
+    (the last case, which takes the row-wise unique)."""
+    keys = np.random.default_rng(cols).integers(lo, hi, (5000, cols))
+    want = np.unique(keys, axis=0, return_inverse=True)[1].reshape(-1)
+    assert np.array_equal(_group_ids(keys), want)
+    assert _group_ids(keys[:0]).shape == (0,)

@@ -7,7 +7,7 @@ import pytest
 
 from repro.kernels.padding import pad_bottom_blocks
 from repro.kernels.splitnn_bottom.kernel import splitnn_bottom_pallas
-from repro.kernels.splitnn_bottom.ops import splitnn_bottom
+from repro.kernels.splitnn_bottom.ops import splitnn_bottom, take_rows
 from repro.kernels.splitnn_bottom.ref import splitnn_bottom_ref
 
 
@@ -164,3 +164,12 @@ def test_impls_share_one_backward():
 
     for gr, gp in zip(loss("ref"), loss("pallas")):
         assert np.array_equal(np.asarray(gr), np.asarray(gp))
+
+
+def test_take_rows_equals_the_row_axis_take():
+    """The row gather of the (M*N, d) view is ``x[:, idx, :]``, repeated
+    and edge rows included."""
+    x, _, _ = _case(m=3, b=37, d=11, seed=3)
+    idx = jnp.asarray([5, 0, 36, 5, 12, 36], jnp.int32)
+    assert np.array_equal(np.asarray(take_rows(x, idx)),
+                          np.asarray(jnp.take(x, idx, axis=1)))

@@ -36,6 +36,13 @@ M, N_ALIGNED, DP, KP, K = 3, 49_152, 128, 128, 12
 P_HI = 1 << 17                       # next_pow2(70_000)
 BLOCK_B, OP = 512, 128
 GATHER_ROWS = GATHER_VMEM_BUDGET // (4 * DP)   # f32 slab at the budget
+# YP (paper Table 1): 510K rows x 90 features over 3 parties, 70/30
+# split -> 357K ids per party, 249,900 aligned rows (padded to the
+# 1024-row k-means block), 30 features per party; linreg bottoms have
+# one output (lane-padded to 128) and no ReLU; the coreset trained at
+# batch 64 holds ~11,700 rows
+YP_ALIGNED, YP_ROWS_PADDED, YP_CORESET, YP_BATCH = (249_900, 250_880,
+                                                    11_698, 64)
 
 f32, i32, i8, u32 = jnp.float32, jnp.int32, jnp.int8, jnp.uint32
 
@@ -159,3 +166,46 @@ def test_kmeans_assign_vmapped_over_clients(chip):
     fn = jax.vmap(lambda p, c: kmeans_assign_pallas(
         p, c, k_real=K, block_n=1024, interpret=False))
     _compile(chip, fn, ((M, N_ALIGNED, DP), f32), ((M, KP, DP), f32))
+
+
+def test_kmeans_update_dense_vmapped_over_clients_at_yp_size(chip):
+    fn = jax.vmap(lambda p, c: kmeans_update_pallas(
+        p, c, k_real=K, n_real=YP_ALIGNED, block_n=1024, interpret=False))
+    _compile(chip, fn, ((M, YP_ROWS_PADDED, DP), f32), ((M, KP, DP), f32))
+
+
+def test_splitnn_bottom_linear_at_the_scoring_shape(chip):
+    """Scoring runs the bottom over one 512-row block at a time."""
+    _compile(chip, lambda x_, w_, b_: splitnn_bottom_pallas(
+        x_, w_, b_, relu=False, block_b=BLOCK_B, interpret=False),
+        ((M, BLOCK_B, DP), f32), ((M, DP, OP), f32), ((M, 1, OP), f32))
+
+
+def test_splitnn_bottom_linear_gather_on_a_yp_coreset_slab(chip):
+    """Training gathers each batch of 64 from the whole coreset slab,
+    whose row count is the slab's own (no multiple of 8)."""
+    assert YP_CORESET * DP * 4 <= GATHER_VMEM_BUDGET
+    _compile(chip, lambda i, x_, w_, b_: splitnn_bottom_gather_pallas(
+        i, x_, w_, b_, relu=False, block_b=YP_BATCH, interpret=False),
+        ((YP_BATCH,), i32), ((M, YP_CORESET, DP), f32), ((M, DP, OP), f32),
+        ((M, 1, OP), f32))
+
+
+def test_bottom_gradient_gathers_rows_without_copying_the_slab(chip):
+    """The backward gathers each batch's rows from the whole coreset slab
+    again for dW.  Gathered as (M, 1, d) slices, XLA relayouts the whole
+    slab on every training step; gathered as rows it copies none."""
+    from repro.kernels.splitnn_bottom.ops import splitnn_bottom
+
+    def grad(w, b, x, i):
+        return jax.grad(lambda w_, b_: jnp.sum(splitnn_bottom(
+            x, w_, b_, False, "ref", YP_BATCH, i) ** 2),
+            argnums=(0, 1))(w, b)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        ((M, DP, 1), f32), ((M, 1), f32), ((M, YP_CORESET, DP), f32),
+        ((YP_BATCH,), i32))]
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    slab = f"f32[{M},{YP_CORESET},{DP}]"
+    assert not [line for line in text.splitlines()
+                if slab in line and " copy(" in line]
