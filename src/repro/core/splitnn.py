@@ -231,13 +231,15 @@ def predict(params, cfg: SplitNNConfig, partition: VerticalPartition, *,
     Historically this pushed the WHOLE partition through the per-client
     loop forward in one unbatched dispatch; it now routes through
     ``repro.serve.vfl.score_partition`` — fixed ``block_b``-row slab
-    batches (remainder zero-padded and truncated), so eval device
-    memory is bounded by one block and the ``splitnn_bottom`` slab
-    kernel is exercised.  Outputs are bitwise-equal to the one-shot
-    forward on full batches (row independence; the scoring forward
-    reproduces ``splitnn_forward``'s reduction order).  ``quant``
-    applies the wire rounding quantized training saw, so quantized
-    checkpoints evaluate under their training numerics."""
+    batches (remainder zero-padded and truncated) mapped over the
+    partition by one device program, so eval device memory is bounded
+    by ``repro.serve.vfl.SCORE_DEVICE_BUDGET`` and the
+    ``splitnn_bottom`` slab kernel is exercised.  Outputs are
+    bitwise-equal to the one-shot forward on full batches (row
+    independence; the scoring forward reproduces ``splitnn_forward``'s
+    reduction order).  ``quant`` applies the wire rounding quantized
+    training saw, so quantized checkpoints evaluate under their
+    training numerics."""
     from repro.serve.vfl import score_partition
 
     out = score_partition(params, cfg, partition, block_b=block_b,

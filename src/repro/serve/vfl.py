@@ -30,11 +30,13 @@ device batch:
   slot-steps and summed occupancy, so the CI counter contract can gate
   the scheduler exactly like the train engine's dispatch/sync contract.
 
-``score_partition`` is the offline/eval flavor — fixed ``block_b``-row
-batches over a whole partition (pad-and-truncate remainder), which is
-what ``splitnn.predict``/``evaluate`` now route through: device memory
-is bounded by one block instead of the full dataset, and the result is
-bitwise-equal to the one-shot ``splitnn_forward`` path.
+``score_partition`` is the offline/eval flavor, which
+``splitnn.predict``/``evaluate`` route through: the whole partition goes
+to the device in one put, one program maps the fixed ``block_b``-row
+batches over it (pad-and-truncate remainder) and the outputs come back
+in one read.  Device memory is bounded by ``SCORE_DEVICE_BUDGET`` (a
+larger partition goes in chunks of equal block count), and the result
+is bitwise-equal to the one-shot ``splitnn_forward`` path.
 
 ``simulate_trace`` drives an engine over an open-loop arrival trace on
 a virtual clock (fixed or measured per-dispatch service time) under two
@@ -53,9 +55,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.padding import round_up
 from repro.obs.metrics import Histogram, StatsMixin
 from repro.obs.trace import span
-from repro.train.vfl import make_score_step, pack_slab
+from repro.train.vfl import make_partition_score, make_score_step
 
 __all__ = [
     "ServeStats", "ScoreRequest", "VFLScoringEngine", "SimReport",
@@ -350,6 +353,13 @@ class VFLScoringEngine:
 
 # ------------------------------------------------------- offline scoring
 
+# Device bytes of the partition's slab one scoring dispatch may hold,
+# counted lane-padded (d_max rounded up to 128), which bounds the layout
+# XLA picks: YP's 153,000 test rows over 3 parties count 235 MB and go
+# in one dispatch (compiled for a v5e, its rows and padded slab take
+# 118 MB); a larger partition goes in chunks of equal block count.
+SCORE_DEVICE_BUDGET = 256 * 2**20
+
 
 def score_partition(params, cfg, partition, *, block_b: int = 512,
                     bottom_impl: str = "ref",
@@ -357,11 +367,15 @@ def score_partition(params, cfg, partition, *, block_b: int = 512,
     """Score a whole ``VerticalPartition`` through fixed-shape batches.
 
     The batched replacement for the historical one-dispatch
-    ``splitnn_forward`` eval: the device sees ``min(block_b, N)``-row
-    slabs (the remainder zero-padded and truncated — row independence
-    makes this exact), so eval memory is bounded by one block and the
-    ``splitnn_bottom`` slab path is exercised.  Returns the raw (N, o)
-    outputs, bitwise-equal to the one-shot forward.
+    ``splitnn_forward`` eval: each client's rows go to the device as
+    they are, in one put, and one program pads and stacks them into a
+    slab of whole ``min(block_b, N)``-row blocks (the remainder
+    zero-padded and truncated — row independence makes this exact),
+    maps the per-block forward over the blocks, and is read back once.
+    Device memory is bounded by ``SCORE_DEVICE_BUDGET``: past it the
+    blocks go in equal chunks, one dispatch each.  Each dispatch is one
+    ``serve.dispatch`` span whose ``blocks`` counts its blocks.  Returns
+    the raw (N, o) outputs, bitwise-equal to the one-shot forward.
     """
     fd = [f.shape[1] for f in partition.client_features]
     n = partition.n_samples
@@ -372,20 +386,25 @@ def score_partition(params, cfg, partition, *, block_b: int = 512,
             o = params["top"]["w2"].shape[1]
         return np.zeros((0, o), np.float32)
     bs = min(int(block_b), n)
-    packed, score = make_score_step(params, cfg, fd,
-                                    bottom_impl=bottom_impl, block_b=bs,
-                                    quant=quant)
-    slab = pack_slab(partition.client_features)          # (M, N, d_max)
-    buf = np.zeros((slab.shape[0], bs, slab.shape[2]), np.float32)
+    n_blocks = -(-n // bs)
+    block_bytes = len(fd) * bs * round_up(max(fd), 128) * 4
+    n_dispatch = -(-n_blocks // max(1, SCORE_DEVICE_BUDGET // block_bytes))
+    blocks = -(-n_blocks // n_dispatch)           # equal blocks per dispatch
+    rows = blocks * bs
+    packed, score = make_partition_score(params, cfg, fd,
+                                         bottom_impl=bottom_impl,
+                                         block_b=bs, quant=quant)
     outs = []
-    for s in range(0, n, bs):
-        e = min(s + bs, n)
-        buf[:, :e - s, :] = slab[:, s:e, :]
-        if e - s < bs:
-            buf[:, e - s:, :] = 0.0
-        with span("serve.dispatch", rows=e - s, slots=bs,
-                  occupancy=e - s, bottom_impl=bottom_impl):
-            outs.append(np.asarray(score(packed, jnp.asarray(buf)))[:e - s])
+    for s in range(0, n, rows):
+        real = min(rows, n - s)
+        xs = [np.asarray(f[s:s + real], np.float32)
+              for f in partition.client_features]
+        if s and real < rows:   # short last chunk: one shape for all
+            xs = [np.pad(x, ((0, rows - real), (0, 0))) for x in xs]
+        with span("serve.dispatch", rows=real, slots=rows, occupancy=real,
+                  blocks=blocks, bottom_impl=bottom_impl):
+            outs.append(np.asarray(score(
+                packed, *[jnp.asarray(x) for x in xs]))[:real])
     return np.concatenate(outs, axis=0)
 
 

@@ -292,6 +292,54 @@ def _score_step_fn(cfg, m: int, bottom_impl: str, block_b: int,
     return jax.jit(score)
 
 
+@functools.lru_cache(maxsize=32)
+def _score_blocks_fn(cfg, m: int, bottom_impl: str, block_b: int,
+                     quant: Optional[str] = None):
+    """One jitted program that scores M clients' (N, d_m) feature rows
+    block by block on the device: it zero-pads and stacks them into the
+    (M, n_blocks * block_b, d_max) slab, then runs a ``lax.map`` whose
+    body is ``_score_step_fn``'s forward on one ``block_b``-row slice,
+    so every row comes out bitwise as a per-block dispatch would give it
+    (int8's 8-row scale groups included).  Returns the
+    (n_blocks * block_b, o) outputs.  Cached like ``_score_step_fn``;
+    jit adds one executable per row count."""
+    def score(packed, *xs):
+        rows = round_up(xs[0].shape[0], block_b)
+        d_max = packed["bw"].shape[1]
+        x_slab = jnp.stack([jnp.pad(x, ((0, rows - x.shape[0]),
+                                        (0, d_max - x.shape[1])))
+                            for x in xs])
+
+        def block(i):
+            xb = jax.lax.dynamic_slice_in_dim(x_slab, i * block_b, block_b,
+                                              axis=1)
+            return forward_slab_eval(packed, cfg, m, xb,
+                                     bottom_impl=bottom_impl,
+                                     block_b=block_b, quant=quant)
+        out = jax.lax.map(block, jnp.arange(rows // block_b))
+        return out.reshape(-1, out.shape[-1])
+    return jax.jit(score)
+
+
+def _packed_on_device(params, feature_dims: Sequence[int]):
+    """``pack_slab_params`` gathered onto the default device (see
+    ``make_score_step``)."""
+    return jax.device_put(pack_slab_params(params, max(feature_dims)),
+                          jax.devices()[0])
+
+
+def make_partition_score(params, cfg, feature_dims: Sequence[int], *,
+                         bottom_impl: str = "ref", block_b: int = 512,
+                         quant: Optional[str] = None):
+    """``(packed, score_blocks)``: ``make_score_step``'s handoff with the
+    many-block program of ``_score_blocks_fn`` in place of the one-block
+    step.  ``score_blocks(packed, *xs)`` takes the M clients' (N, d_m)
+    rows and returns the (N rounded up to whole blocks, o) outputs."""
+    fd = tuple(int(d) for d in feature_dims)
+    return _packed_on_device(params, fd), _score_blocks_fn(
+        cfg, len(fd), bottom_impl, int(block_b), resolve_quant(quant))
+
+
 def make_score_step(params, cfg, feature_dims: Sequence[int], *,
                     bottom_impl: str = "ref", block_b: int = 512,
                     quant: Optional[str] = None):
@@ -311,10 +359,8 @@ def make_score_step(params, cfg, feature_dims: Sequence[int], *,
     a ``shard_map``.  So ``packed`` is gathered onto the default device.
     """
     fd = tuple(int(d) for d in feature_dims)
-    packed = jax.device_put(pack_slab_params(params, max(fd)),
-                            jax.devices()[0])
-    return packed, _score_step_fn(cfg, len(fd), bottom_impl, int(block_b),
-                                  resolve_quant(quant))
+    return _packed_on_device(params, fd), _score_step_fn(
+        cfg, len(fd), bottom_impl, int(block_b), resolve_quant(quant))
 
 
 # -------------------------------------------------------------- loss sums
@@ -801,6 +847,7 @@ def clear_program_caches() -> None:
     """
     from repro.core.coreset import clear_fit_cache
     _score_step_fn.cache_clear()
+    _score_blocks_fn.cache_clear()
     make_epoch_fn.cache_clear()
     _loop_step_fn.cache_clear()
     clear_fit_cache()

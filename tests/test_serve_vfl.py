@@ -13,8 +13,11 @@ import pytest
 from conftest import make_cls_partition
 from repro.core import splitnn as models
 from repro.core.splitnn import SplitNNConfig, evaluate, predict, train_splitnn
+from repro.obs import Tracer, use_tracer
+from repro.serve import vfl
 from repro.serve.vfl import (ScoreRequest, ServeStats, VFLScoringEngine,
                              score_partition, simulate_trace)
+from repro.train.vfl import make_score_step
 
 
 def _setup(model="mlp", n_classes=4, n=96, d=11, seed=1):
@@ -74,6 +77,69 @@ def test_predict_evaluate_routed_through_batches():
         assert np.array_equal(predict(params, cfg, part, block_b=bb), ref)
     acc_ref = float(np.mean(ref == part.labels))
     assert evaluate(params, cfg, part, block_b=32) == acc_ref
+
+
+def _per_block(params, cfg, part, block_b, bottom_impl, quant):
+    """The partition scored one block per dispatch by the one-block
+    program (``_score_step_fn``), remainder zero-padded and truncated."""
+    fd = [f.shape[1] for f in part.client_features]
+    packed, step = make_score_step(params, cfg, fd, bottom_impl=bottom_impl,
+                                   block_b=block_b, quant=quant)
+    n = part.n_samples
+    slab = np.zeros((len(fd), -(-n // block_b) * block_b, max(fd)),
+                    np.float32)
+    for i, f in enumerate(part.client_features):
+        slab[i, :n, :f.shape[1]] = f
+    return np.concatenate([
+        np.asarray(step(packed, jnp.asarray(slab[:, s:s + block_b])))
+        for s in range(0, n, block_b)])[:n]
+
+
+def _traced_score(params, cfg, part, **kw):
+    tracer = Tracer()
+    with use_tracer(tracer):
+        out = score_partition(params, cfg, part, **kw)
+    return out, tracer.by_name("serve.dispatch")
+
+
+def test_score_partition_is_one_dispatch_within_the_budget():
+    part, cfg, params = _setup("mlp", 4, n=150)
+    out, spans = _traced_score(params, cfg, part, block_b=32)
+    assert len(spans) == 1
+    assert spans[0].attrs["blocks"] == 5                   # ceil(150 / 32)
+    assert spans[0].attrs["rows"] == 150
+    assert out.shape == (150, 4)
+
+
+@pytest.mark.parametrize("quant", [None, "int8"])
+@pytest.mark.parametrize("bottom_impl", ["ref", "pallas"])
+@pytest.mark.parametrize("model,n_classes", [("lr", 2), ("mlp", 4),
+                                             ("linreg", 0)])
+def test_score_partition_equals_the_per_block_program(model, n_classes,
+                                                      bottom_impl, quant):
+    """The device loop over blocks gives the bits of one dispatch per
+    block of the one-block program (int8's 8-row scale groups too)."""
+    part, cfg, params = _setup(model, n_classes, n=150)
+    out = score_partition(params, cfg, part, block_b=64,
+                          bottom_impl=bottom_impl, quant=quant)
+    ref = _per_block(params, cfg, part, 64, bottom_impl, quant)
+    assert out.shape == ref.shape
+    assert np.array_equal(out, ref)
+
+
+def test_score_partition_splits_past_the_budget_into_equal_chunks(
+        monkeypatch):
+    """A budget of three 32-row blocks (3 clients x 32 rows x 128 lanes
+    x 4 B each) splits 300 rows (10 blocks) into 4 dispatches of 3
+    blocks, with the bits of the one-dispatch run."""
+    part, cfg, params = _setup("lr", 2, n=300)
+    whole, spans = _traced_score(params, cfg, part, block_b=32)
+    assert [s.attrs["blocks"] for s in spans] == [10]
+    monkeypatch.setattr(vfl, "SCORE_DEVICE_BUDGET", 3 * 3 * 32 * 128 * 4)
+    out, spans = _traced_score(params, cfg, part, block_b=32)
+    assert [s.attrs["blocks"] for s in spans] == [3, 3, 3, 3]
+    assert [s.attrs["rows"] for s in spans] == [96, 96, 96, 12]
+    assert np.array_equal(out, whole)
 
 
 def test_streamed_matches_oneshot_bitwise():

@@ -209,3 +209,32 @@ def test_bottom_gradient_gathers_rows_without_copying_the_slab(chip):
     slab = f"f32[{M},{YP_CORESET},{DP}]"
     assert not [line for line in text.splitlines()
                 if slab in line and " copy(" in line]
+
+
+@pytest.mark.parametrize("model,n_classes,widths,n_test", [
+    ("linreg", 0, (30, 30, 30), 153_000),      # YP's 30% test rows
+    ("mlp", 2, (11, 11, 10), 30_000)])         # HI's
+def test_whole_partition_scoring_program(chip, monkeypatch, model, n_classes,
+                                         widths, n_test):
+    """Scoring maps the 512-row block forward over the whole test
+    partition in one program: the bottom kernel sits in the device loop
+    as a Mosaic call.  The ops wrapper asks the backend whether to
+    interpret, and the backend here is the CPU, so it is told not to."""
+    from repro.core import splitnn as models
+    from repro.core.splitnn import SplitNNConfig
+    from repro.kernels.splitnn_bottom import ops
+    from repro.train.vfl import _score_blocks_fn, pack_slab_params
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    cfg = SplitNNConfig(model=model, n_classes=n_classes)
+    packed = jax.eval_shape(lambda: pack_slab_params(
+        models.init_splitnn(cfg, list(widths)), max(widths)))
+    packed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        packed)
+    xs = [jax.ShapeDtypeStruct((n_test, d), f32, sharding=chip)
+          for d in widths]
+    # a fresh jit, so no trace made for the CPU elsewhere is reused
+    fn = _score_blocks_fn.__wrapped__(cfg, M, "pallas", BLOCK_B, None)
+    text = fn.lower(packed, *xs).compile().as_text()
+    assert "tpu_custom_call" in text
