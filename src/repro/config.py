@@ -8,8 +8,8 @@ fenced by two frozen dataclasses:
 
 ``EngineOptions``
     Knobs of the *compiled execution* layer — where programs run and in
-    what shape: ``mesh``/``shard_axis`` (DESIGN.md §5/§8), the training
-    engine and bottom kernel, the gather fusion, the batch tile, the
+    what shape: ``mesh``/``shard_axis`` (DESIGN.md §5/§8), the bottom
+    kernel, the gather fusion, the batch tile, the
     activation wire dtype (§12), and the tracer (§10).
 
 ``AlignOptions``
@@ -26,7 +26,7 @@ tuple, and ``lru_cache`` factories can key on whole option objects.
 Legacy kwargs still work everywhere through ONE shim,
 ``_coerce_options``: every public entry point collects ``**legacy``,
 routes each key to the options class that owns it (honouring renames
-like ``engine=`` → ``train_engine``/``backend=`` → ``psi_backend``),
+like ``backend=`` → ``psi_backend``),
 warns ``DeprecationWarning`` once, and builds the same frozen object
 the new path receives — so the two call styles are bitwise-identical by
 construction (property-tested in tests/test_config.py).  Mixing a
@@ -40,8 +40,7 @@ import dataclasses
 import warnings
 from typing import Any, Dict, Optional, Tuple
 
-__all__ = ["EngineOptions", "AlignOptions", "ENGINE_ALIASES",
-           "ALIGN_ALIASES"]
+__all__ = ["EngineOptions", "AlignOptions", "ALIGN_ALIASES"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,19 +49,28 @@ class EngineOptions:
 
     ``mesh``/``shard_axis`` shard every device stage through one knob
     (1-D ``("data",)`` or 2-D ``(data, model)`` meshes);
-    ``train_engine`` picks "scan" (compiled epoch engine) or "loop"
-    (legacy parity oracle); ``bottom_impl``/``fuse_gather``/``block_b``
-    configure the block-diagonal bottom pass; ``quant`` narrows the
-    activation wire dtype ("int8"|"fp8"); ``trace`` turns on the obs
-    layer (a ``repro.obs.Tracer`` or any truthy value)."""
+    ``bottom_impl`` ("ref"|"pallas")/``fuse_gather``/``block_b``
+    configure the block-diagonal bottom pass of the one training engine
+    (``repro.train.vfl.train_scan``); ``quant`` narrows the activation
+    wire dtype ("int8"|"fp8"); ``trace`` turns on the obs layer (a
+    ``repro.obs.Tracer`` or any truthy value).
+
+    ``train_engine`` is not a field: the benchmark's tests still pass
+    ``train_engine="scan"``, the only engine there is, so that value is
+    accepted and dropped, and any other is a ``ValueError``."""
     mesh: Any = None
     shard_axis: Optional[str] = None
-    train_engine: str = "scan"
+    train_engine: dataclasses.InitVar[str] = "scan"
     bottom_impl: str = "ref"
     fuse_gather: bool = True
     block_b: int = 512
     quant: Optional[str] = None
     trace: Any = None
+
+    def __post_init__(self, train_engine: str) -> None:
+        if train_engine != "scan":
+            raise ValueError(f"train_engine must be 'scan', the only "
+                             f"engine, not {train_engine!r}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,7 +105,6 @@ class AlignOptions:
 
 
 # legacy kwarg name -> options field (identity names resolve implicitly)
-ENGINE_ALIASES: Dict[str, str] = {"engine": "train_engine"}
 ALIGN_ALIASES: Dict[str, str] = {"backend": "psi_backend",
                                  "engine_impl": "impl"}
 

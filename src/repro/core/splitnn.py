@@ -15,10 +15,10 @@ diagonal bottom layer — fused into one slab pass by
 per sample per step, the "instance-wise communication" whose reduction by
 coreset training the paper measures.
 
-Training itself lives in ``repro.train.vfl`` (DESIGN.md §7): a scan-based
-epoch engine (one dispatch + one host sync per epoch, mesh-shardable) and
-the legacy per-step loop kept as its parity oracle.  ``train_splitnn``
-here is the thin stage entry point the pipeline calls.
+Training itself lives in ``repro.train.vfl`` (DESIGN.md §7): one
+scan-based epoch engine (one dispatch + one host sync per epoch,
+mesh-shardable).  ``train_splitnn`` here is the thin stage entry point
+the pipeline calls.
 
 Models: LR / MLP (classification), LinearReg (regression) as SplitNN;
 KNN as distributed distance aggregation (squared L2 decomposes per client).
@@ -109,8 +109,9 @@ def init_splitnn(cfg: SplitNNConfig, feature_dims: Sequence[int]):
 def splitnn_forward(params, cfg: SplitNNConfig, xs: Sequence[jnp.ndarray]):
     """xs: per-client feature slices [(B, d_m)]. Returns logits/preds (B, o).
 
-    Per-client loop form — the slab form (one fused block-diagonal pass
-    over all M clients) is ``repro.train.vfl.forward_slab_packed``.
+    Per-client loop form, the plain reference the tests train against —
+    the slab form (one fused block-diagonal pass over all M clients) is
+    ``repro.train.vfl.forward_slab_packed``.
     """
     acts = []
     for bp, x in zip(params["bottoms"], xs):
@@ -127,17 +128,13 @@ def splitnn_forward(params, cfg: SplitNNConfig, xs: Sequence[jnp.ndarray]):
 
 
 def _loss_from_out(out, cfg: SplitNNConfig, y, w):
-    """Eq.(2) weighted loss from model output (shared by both engines)."""
+    """Eq.(2) weighted loss from model output."""
     if cfg.n_classes == 0:
         return weighted_mse(out[:, 0:1], y[:, None], w)
     if cfg.n_classes == 2 and out.shape[-1] == 1:
         from repro.train.losses import weighted_binary_xent
         return weighted_binary_xent(out[:, 0], y, w)
     return weighted_softmax_xent(out, y, w)
-
-
-def _loss_fn(params, cfg: SplitNNConfig, xs, y, w):
-    return _loss_from_out(splitnn_forward(params, cfg, xs), cfg, y, w)
 
 
 def activation_width(cfg: SplitNNConfig) -> int:
@@ -174,48 +171,28 @@ def train_splitnn(partition: VerticalPartition, cfg: SplitNNConfig, *,
                   **legacy) -> TrainReport:
     """Mini-batch Adam training to the paper's convergence criterion.
 
-    Thin stage entry point over ``repro.train.vfl``.  Engine knobs live
-    on ``options=EngineOptions(...)`` (``repro.config``; legacy
-    ``engine=``/``mesh=``/``bottom_impl=``/... kwargs coerce through
-    the shared shim with a ``DeprecationWarning``, bitwise-identical):
-
-    - ``train_engine="scan"`` (default): compiled epoch engine — one
-      dispatch and one host sync per epoch, remainder batches
-      pad-and-masked, ``mesh``/``shard_axis`` shard the per-step batch
-      axis over ``data`` and (on a 2-D ``(data, model)`` mesh) the
-      M-client bottom axis over ``model`` (DESIGN.md §8),
-      ``bottom_impl`` selects the block-diagonal bottom layer ("ref"
-      slab oracle / "pallas" fused kernel / "loop" per-client), and
-      ``fuse_gather`` scalar-prefetches the per-step schedule indices
-      into that pass (bitwise-equal to the explicit ``slab[:, idx, :]``
-      gather).
-    - ``train_engine="loop"``: the legacy per-minibatch host loop
-      (parity oracle and dispatch-overhead baseline; single-device
-      only, f32 only — ``quant`` needs the scan engine's slab path).
+    Thin stage entry point over the one engine, ``repro.train.vfl.
+    train_scan``.  Engine knobs live on ``options=EngineOptions(...)``
+    (``repro.config``; legacy ``mesh=``/``bottom_impl=``/... kwargs
+    coerce through the shared shim with a ``DeprecationWarning``,
+    bitwise-identical): one dispatch and one host sync per epoch,
+    remainder batches pad-and-masked, ``mesh``/``shard_axis`` shard the
+    per-step batch axis over ``data`` and (on a 2-D ``(data, model)``
+    mesh) the M-client bottom axis over ``model`` (DESIGN.md §8),
+    ``bottom_impl`` selects the block-diagonal bottom layer ("ref" slab
+    oracle / "pallas" fused kernel), and ``fuse_gather``
+    scalar-prefetches the per-step schedule indices into that pass
+    (bitwise-equal to the explicit ``slab[:, idx, :]`` gather).
 
     ``quant`` ("int8"|"fp8", DESIGN.md §12) quantizes the per-step
     activation send (and, for int8, the bottom GEMM) to a 1-byte wire
     dtype with pow2 block scales.
     """
-    from repro.config import ENGINE_ALIASES, EngineOptions, _coerce_options
-    from repro.quant import resolve_quant
+    from repro.config import EngineOptions, _coerce_options
     from repro.train import vfl
 
     (options,) = _coerce_options(
-        "train_splitnn", legacy, ("options", EngineOptions, options,
-                                  ENGINE_ALIASES))
-    if options.train_engine == "loop":
-        if options.mesh is not None:
-            raise ValueError("engine='loop' does not shard; use the scan "
-                             "engine for mesh training")
-        if resolve_quant(options.quant) is not None:
-            raise ValueError("engine='loop' communicates f32 only; use the "
-                             "scan engine for quantized training")
-        return vfl.train_loop(partition, cfg, sample_weights=sample_weights,
-                              bandwidth=bandwidth, latency=latency,
-                              verbose=verbose)
-    if options.train_engine != "scan":
-        raise ValueError(options.train_engine)
+        "train_splitnn", legacy, ("options", EngineOptions, options, {}))
     return vfl.train_scan(partition, cfg, sample_weights=sample_weights,
                           bandwidth=bandwidth, latency=latency,
                           options=options, verbose=verbose)

@@ -18,8 +18,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.config import (ALIGN_ALIASES, ENGINE_ALIASES, AlignOptions,
-                          EngineOptions, _coerce_options)
+from repro.config import (ALIGN_ALIASES, AlignOptions, EngineOptions,
+                          _coerce_options)
 from repro.core.coreset import CoresetResult, cluster_coreset
 from repro.core.mpsi import MPSI, MPSIStats
 from repro.core.splitnn import (SplitNNConfig, TrainReport, evaluate,
@@ -159,12 +159,11 @@ def run_pipeline(train_part: VerticalPartition,
     ``model`` (the client→server activation send lowers to one
     all-gather; DESIGN.md §8) — training matches single-device within
     gemm/psum-reassociation ulps (DESIGN.md §5, §7).
-    ``options.train_engine``/``bottom_impl`` select the training engine
-    and the block-diagonal bottom implementation ("pallas" = the fused
-    VMEM-resident kernel on real TPU); ``fuse_gather``/``block_b``
-    thread through to ``train_splitnn`` (the scalar-prefetch
-    schedule-gather toggle and the bottom kernel's batch tile).
-    Evaluation reuses ``block_b`` and, for the slab impls,
+    ``options.bottom_impl`` selects the block-diagonal bottom
+    implementation ("ref" | "pallas" = the fused VMEM-resident kernel on
+    real TPU); ``fuse_gather``/``block_b`` thread through to
+    ``train_splitnn`` (the scalar-prefetch schedule-gather toggle and
+    the bottom kernel's batch tile).  Evaluation reuses ``block_b`` and
     ``bottom_impl`` through the batched scoring path.
     ``options.quant`` ("int8"|"fp8", DESIGN.md §12) quantizes the
     training stage's per-step activation send (int8 also runs the int8
@@ -180,7 +179,7 @@ def run_pipeline(train_part: VerticalPartition,
     counters (dispatches/host syncs) are unchanged by it."""
     options, align = _coerce_options(
         "run_pipeline", legacy,
-        ("options", EngineOptions, options, ENGINE_ALIASES),
+        ("options", EngineOptions, options, {}),
         ("align", AlignOptions, align, ALIGN_ALIASES))
     align = align.with_engine_defaults(options)
     variant = variant.lower()
@@ -249,7 +248,6 @@ def run_pipeline(train_part: VerticalPartition,
                                        params=None)
         else:
             tr_sp = span("pipeline.train", model=cfg.model,
-                         engine=options.train_engine,
                          rows=train_data.n_samples)
             t0 = now()
             with tr_sp:
@@ -261,13 +259,10 @@ def run_pipeline(train_part: VerticalPartition,
                       epochs=train_report.epochs)
             train_secs = (train_report.train_seconds
                           + train_report.simulated_comm_seconds)
-            eval_impl = (options.bottom_impl
-                         if options.bottom_impl in ("ref", "pallas")
-                         else "ref")
             with span("pipeline.serve", rows=test_part.n_samples):
                 metric = evaluate(train_report.params, cfg, test_part,
                                   block_b=options.block_b,
-                                  bottom_impl=eval_impl,
+                                  bottom_impl=options.bottom_impl,
                                   quant=options.quant)
 
     return PipelineReport(

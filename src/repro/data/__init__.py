@@ -1,10 +1,8 @@
 from repro.data.synthetic import (DATASETS, DatasetSpec, make_dataset,
                                   make_id_universe)
 from repro.data.vertical import VerticalPartition, partition_features
-from repro.data.pipeline import batch_iterator, token_batch_iterator
 
 __all__ = [
     "DATASETS", "DatasetSpec", "make_dataset", "make_id_universe",
     "VerticalPartition", "partition_features",
-    "batch_iterator", "token_batch_iterator",
 ]

@@ -11,8 +11,6 @@
   splitnn_bottom  — fused block-diagonal VFL bottom layer: all M
                     clients' relu(x_m @ w_m + b_m) in one pass, weight
                     blocks VMEM-resident across batch tiles (§7)
-  flash_attention — online-softmax GQA attention (SplitNN LLM train/serve)
-  ssd_scan        — Mamba2 SSD chunked scan with VMEM-carried state
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper, padding + layout), ref.py (pure-jnp oracle). The ops

@@ -1,25 +1,12 @@
-"""Production mesh definitions (TPU v5e pods).
-
-single pod : (16, 16)    axes ("data", "model")          — 256 chips
-multi-pod  : (2, 16, 16) axes ("pod", "data", "model")   — 512 chips
+"""Mesh factories for the device stages: the 1-D ``("data",)`` mesh of
+the PSI/CSS batch paths and the 2-D ``(data, model)`` train mesh.
 
 Functions (not module constants) so importing never touches jax device
-state — the dry-run sets XLA_FLAGS before first jax init.
+state — a caller may set XLA_FLAGS before the first jax init.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh():
-    """1-device mesh for CPU smoke tests (same axis names as production)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
 
 
 def make_data_mesh(n_devices: int | None = None, *, model: int = 1):

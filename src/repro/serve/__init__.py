@@ -1,11 +1,8 @@
-from repro.serve.engine import (greedy_decode, make_prefill_step,
-                                make_serve_step)
 from repro.serve.vfl import (ScoreRequest, ServeStats, SimReport,
                              VFLScoringEngine, score_partition,
                              simulate_trace)
 
 __all__ = [
-    "make_serve_step", "make_prefill_step", "greedy_decode",
     "ScoreRequest", "ServeStats", "SimReport", "VFLScoringEngine",
     "score_partition", "simulate_trace",
 ]

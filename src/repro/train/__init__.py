@@ -1,13 +1,10 @@
-from repro.train.optimizer import adam_init, adam_update, sgd_init, sgd_update
+from repro.train.optimizer import adam_init, adam_update
 from repro.train.losses import (weighted_softmax_xent, weighted_mse,
                                 weighted_binary_xent)
-from repro.train.steps import make_train_step, make_eval_step
-from repro.train.vfl import (EngineStats, TrainReport, train_loop,
-                             train_scan)
+from repro.train.vfl import EngineStats, TrainReport, train_scan
 
 __all__ = [
-    "adam_init", "adam_update", "sgd_init", "sgd_update",
+    "adam_init", "adam_update",
     "weighted_softmax_xent", "weighted_mse", "weighted_binary_xent",
-    "make_train_step", "make_eval_step",
-    "EngineStats", "TrainReport", "train_loop", "train_scan",
+    "EngineStats", "TrainReport", "train_scan",
 ]

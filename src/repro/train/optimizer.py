@@ -1,8 +1,8 @@
-"""Optimizers in pure JAX (pytree-based, no optax dependency).
+"""The Adam optimizer in pure JAX (pytree-based, no optax dependency).
 
 Adam follows Kingma & Ba [arXiv:1412.6980], the paper's optimizer choice
 (§5.1 Protocols). States are pytrees matching the param tree, so they shard
-with the same FSDP rules as the params.
+with the same specs as the params.
 """
 from __future__ import annotations
 
@@ -51,15 +51,3 @@ def adam_update(params, grads, state: AdamState, *, lr: float = 1e-3,
     new_m = treedef.unflatten([o[1] for o in out])
     new_v = treedef.unflatten([o[2] for o in out])
     return new_p, AdamState(step=step, mu=new_m, nu=new_v)
-
-
-def sgd_init(params):
-    return jnp.zeros((), jnp.int32)
-
-
-def sgd_update(params, grads, state, *, lr: float = 0.1, **_):
-    new_p = jax.tree_util.tree_map(
-        lambda p, g: (p.astype(jnp.float32)
-                      - lr * g.astype(jnp.float32)).astype(p.dtype),
-        params, grads)
-    return new_p, state + 1

@@ -1,16 +1,13 @@
-"""Compiled VFL training engines (paper §3 training stage, DESIGN.md §7–§8).
+"""The compiled VFL training engine (paper §3 training stage, DESIGN.md §7–§8).
 
-Two engines drive the SplitNN runtime (model zoo in
-``repro.core.splitnn``):
-
-``train_scan`` — the device engine.  One epoch is ONE compiled dispatch:
+``train_scan`` drives the SplitNN runtime (model zoo in
+``repro.core.splitnn``).  One epoch is ONE compiled dispatch:
 a ``lax.scan`` over a precomputed permutation schedule with the
 ``(params, opt)`` carry donated between epochs, per-step minibatch
 gather + forward/backward/Adam in-graph, and the epoch loss accumulated
 on device.  The host syncs exactly once per epoch (the ``float(loss)``
-that feeds the paper's convergence-window check) instead of once per
-minibatch — the legacy loop paid one dispatch *and* one blocking sync
-per step.  Remainder batches are padded to the step shape and masked
+that feeds the paper's convergence-window check), not once per
+minibatch.  Remainder batches are padded to the step shape and masked
 out through the Eq.(2) sample weights (w = 0 rows contribute exactly
 0.0 to every loss sum and gradient), so the last ``n mod bs`` rows
 train instead of being dropped.  The M-client bottom layer runs as one
@@ -39,16 +36,8 @@ With ``mesh=`` the engine shards over a 1-D ``("data",)`` or 2-D
   cotangent for ITS activation block — free of redundancy factors:
   bottom grads psum over ``data`` only, top grads over both axes.
 
-``train_loop`` — the legacy host epoch loop (one jit dispatch + one
-blocking sync per minibatch), kept as the parity oracle and timing
-baseline.  Its remainder-batch drop is fixed here too: every epoch
-trains all n rows, and ``comm_bytes`` counts the actual rows of the
-partial batch.
-
-Both return the same ``TrainReport`` (byte-compatible with the
-pre-refactor report; ``engine_stats`` is appended with a default for
-old constructors) and share the convergence criterion: |loss[-1-w] -
-loss[-1]| < eps over the epoch-loss trace.
+It returns a ``TrainReport`` and stops on the paper's convergence
+criterion: |loss[-1-w] - loss[-1]| < eps over the epoch-loss trace.
 """
 from __future__ import annotations
 
@@ -79,9 +68,9 @@ class EngineStats(StatsMixin):
 
     ``dispatches`` counts compiled-function invocations in the timed
     training loop; ``host_syncs`` counts blocking device→host transfers
-    (the scan engine's contract is exactly one of each per epoch; the
-    legacy loop pays one of each per minibatch step).  The one-time
-    compile/warm-up dispatch before the timed region is excluded.
+    (the engine's contract is exactly one of each per epoch).  The
+    one-time compile/warm-up dispatch before the timed region is
+    excluded.
     ``shards``/``model_shards`` are the (data, model) mesh-axis sizes
     the run sharded over (1 = unsharded).
 
@@ -100,7 +89,6 @@ class EngineStats(StatsMixin):
     shards: int = 1
     steps_per_epoch: int = 0
     padded_batch: int = 0
-    engine: str = "scan"
     bottom_impl: str = "ref"
     model_shards: int = 1
     fused_gather: bool = False
@@ -423,12 +411,10 @@ def bottom_path(bottom_impl: str, fuse_gather: bool, n: int, d: int,
     """Which bottom pass the epoch program runs over an (M, n, d) slab,
     by the ``record_path`` name of ``splitnn_bottom``'s wrapper: the
     gather-fused kernel or its past-budget fallback
-    (``gather_path``), "dense" (pallas, gather first), "ref" (the jnp
-    slab oracle) or "loop" (per-client matmuls)."""
+    (``gather_path``), "dense" (pallas, gather first) or "ref" (the jnp
+    slab oracle)."""
     from repro.kernels.splitnn_bottom.ops import gather_path
 
-    if bottom_impl not in ("ref", "pallas"):
-        return "loop"
     if not fuse_gather:
         return "dense" if bottom_impl == "pallas" else "ref"
     return gather_path(bottom_impl, n, d, quant)
@@ -458,8 +444,6 @@ class EpochProgram:
     n_model: int
     bottom_impl: str
     fuse_gather: bool
-    use_slab: bool
-    n_data_arrays: int
     m_pad: int
     d_eff: int                       # slab feature width the program expects
     param_shapes: Any                # eval_shape of the fresh carry
@@ -493,16 +477,12 @@ class EpochProgram:
         sds = jax.ShapeDtypeStruct
         idx = sds((steps, padded_bs), jnp.int32)
         mask = sds((steps, padded_bs), jnp.float32)
-        if self.use_slab:
-            data = (sds((self.m_pad, n, self.d_eff), jnp.float32),)
-        else:
-            data = tuple(sds((n, d), jnp.float32)
-                         for d in self.feature_dims)
+        slab = sds((self.m_pad, n, self.d_eff), jnp.float32)
         y = sds((n,), jnp.float32 if self.cfg.n_classes == 0
                 else jnp.int32)
         w = sds((n,), jnp.float32)
         opt_shapes = jax.eval_shape(adam_init, self.param_shapes)
-        return (self.param_shapes, opt_shapes, idx, mask) + data + (y, w)
+        return (self.param_shapes, opt_shapes, idx, mask, slab, y, w)
 
 
 @functools.lru_cache(maxsize=16)
@@ -522,35 +502,27 @@ def make_epoch_fn(cfg, feature_dims: Tuple[int, ...], mesh,
 
     m = len(feature_dims)
     d_max = max(feature_dims)
-    use_slab = bottom_impl in ("ref", "pallas")
     m_pad = padded_rows(m, n_model)
-    n_data_arrays = 1 if use_slab else m
-    d_eff = (round_up(d_max, 128)
-             if use_slab and fuse_gather and bottom_impl == "pallas"
+    d_eff = (round_up(d_max, 128) if fuse_gather and bottom_impl == "pallas"
              else d_max)
 
-    def fresh_shapes():
-        zoo = models.init_splitnn(cfg, list(feature_dims))
-        return pack_slab_params(zoo, d_max, m_pad) if use_slab else zoo
-    param_shapes = jax.eval_shape(fresh_shapes)
+    param_shapes = jax.eval_shape(lambda: pack_slab_params(
+        models.init_splitnn(cfg, list(feature_dims)), d_max, m_pad))
 
-    def batch_forward(p, ib, xs_arrays, shard_model):
+    def batch_forward(p, ib, slab, shard_model):
         maxis = model_axis if shard_model else None
-        if use_slab:
-            if fuse_gather:
-                return forward_slab_packed(p, cfg, m, xs_arrays[0],
-                                           bottom_impl=bottom_impl,
-                                           block_b=block_b, idx=ib,
-                                           model_axis=maxis, quant=quant)
-            return forward_slab_packed(p, cfg, m, xs_arrays[0][:, ib, :],
+        if fuse_gather:
+            return forward_slab_packed(p, cfg, m, slab,
                                        bottom_impl=bottom_impl,
-                                       block_b=block_b, model_axis=maxis,
-                                       quant=quant)
-        return models.splitnn_forward(p, cfg, [x[ib] for x in xs_arrays])
+                                       block_b=block_b, idx=ib,
+                                       model_axis=maxis, quant=quant)
+        return forward_slab_packed(p, cfg, m, slab[:, ib, :],
+                                   bottom_impl=bottom_impl,
+                                   block_b=block_b, model_axis=maxis,
+                                   quant=quant)
 
     def epoch_body(params, opt, idx, mask, arrays, *, sharded):
-        xs_arrays = arrays[:n_data_arrays]
-        y_a, w_a = arrays[n_data_arrays], arrays[n_data_arrays + 1]
+        slab, y_a, w_a = arrays
         steps = idx.shape[0]
 
         def body(carry, sched):
@@ -561,11 +533,11 @@ def make_epoch_fn(cfg, feature_dims: Tuple[int, ...], mesh,
             if not sharded:
                 loss, grads = jax.value_and_grad(
                     lambda pp: models._loss_from_out(
-                        batch_forward(pp, ib, xs_arrays, False),
+                        batch_forward(pp, ib, slab, False),
                         cfg, y, w))(p)
             else:
                 def s_fn(pp):
-                    out = batch_forward(pp, ib, xs_arrays,
+                    out = batch_forward(pp, ib, slab,
                                         model_axis is not None)
                     s, wsum = _loss_sums(out, cfg, y, w)
                     if model_axis is not None:
@@ -614,7 +586,7 @@ def make_epoch_fn(cfg, feature_dims: Tuple[int, ...], mesh,
                 return P()
             return jax.tree_util.tree_map(one, tree)
 
-        if use_slab and model_axis is not None:
+        if model_axis is not None:
             pspec = dict(leaf_specs(
                 {k: v for k, v in param_shapes.items() if k != "top"},
                 True))
@@ -622,7 +594,7 @@ def make_epoch_fn(cfg, feature_dims: Tuple[int, ...], mesh,
             data_specs = (P(model_axis),)
         else:
             pspec = leaf_specs(param_shapes, False)
-            data_specs = (P(),) * n_data_arrays
+            data_specs = (P(),)
         from repro.train.optimizer import AdamState
         ospec = AdamState(step=P(), mu=pspec, nu=pspec)
         in_specs = (pspec, ospec, P(None, data_axis), P(None, data_axis)) \
@@ -642,8 +614,7 @@ def make_epoch_fn(cfg, feature_dims: Tuple[int, ...], mesh,
         jitted=jitted, cfg=cfg, feature_dims=feature_dims, mesh=mesh,
         data_axis=data_axis, model_axis=model_axis, n_data=n_data,
         n_model=n_model, bottom_impl=bottom_impl,
-        fuse_gather=fuse_gather, use_slab=use_slab,
-        n_data_arrays=n_data_arrays, m_pad=m_pad, d_eff=d_eff,
+        fuse_gather=fuse_gather, m_pad=m_pad, d_eff=d_eff,
         param_shapes=param_shapes, pspec=pspec, ospec=ospec,
         data_specs=data_specs, quant=quant)
 
@@ -659,25 +630,27 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
     engine entry takes only the config object):
 
     ``bottom_impl``: "ref" (block-diagonal slab oracle, one batched
-    GEMM) | "pallas" (fused VMEM-resident kernel) | "loop" (legacy
-    per-client matmuls inside the scan, the bitwise-parity oracle for
-    the slab layout).  ``fuse_gather`` fuses the per-step schedule
+    GEMM) | "pallas" (fused VMEM-resident kernel); any other value is a
+    ``ValueError``.  ``fuse_gather`` fuses the per-step schedule
     gather into the slab pass (bitwise-equal to ``False``, which keeps
-    the explicit ``slab[:, idx, :]`` round trip — the parity oracle).
+    the explicit ``slab[:, idx, :]`` round trip).
     ``mesh`` shards the per-step batch axis over ``data`` and, on a 2-D
     ``(data, model)`` mesh, the M-client bottom axis over ``model``
     (DESIGN.md §8); results match single-device within reassociation
     ulps either way.  ``quant`` ("int8"|"fp8", DESIGN.md §12) narrows
     the per-step activation send to a 1-byte wire dtype (int8 also runs
-    the int8 bottom kernels); needs the slab bottom path.
+    the int8 bottom kernels).
     """
     from repro.config import EngineOptions
     from repro.core import splitnn as models
 
-    with span("train.setup", engine="scan", rows=partition.n_samples,
+    with span("train.setup", rows=partition.n_samples,
               clients=partition.n_clients) as setup_sp:
         options = options or EngineOptions()
         bottom_impl = options.bottom_impl
+        if bottom_impl not in ("ref", "pallas"):
+            raise ValueError(f"bottom_impl must be 'ref' or 'pallas', "
+                             f"not {bottom_impl!r}")
         block_b = options.block_b
         fuse_gather = options.fuse_gather
         quant = options.quant
@@ -690,16 +663,7 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
         mesh, data_axis, n_data, model_axis, n_model = resolve_train_mesh(
             options.mesh, options.shard_axis)
 
-        use_slab = bottom_impl in ("ref", "pallas")
-        if n_model > 1 and not use_slab:
-            raise ValueError(
-                "model-axis sharding needs the slab bottom path "
-                "(bottom_impl='ref'|'pallas'), not 'loop'")
         quant = resolve_quant(quant)
-        if quant is not None and not use_slab:
-            raise ValueError(
-                "quantized activations need the slab bottom path "
-                "(bottom_impl='ref'|'pallas'), not 'loop'")
 
         prog = make_epoch_fn(cfg, tuple(int(d) for d in feature_dims), mesh,
                              data_axis, model_axis, n_data, n_model,
@@ -708,8 +672,8 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
         m_pad = prog.m_pad                           # dummy clients (§8)
 
         def fresh_params():
-            zoo = models.init_splitnn(cfg, feature_dims)
-            return pack_slab_params(zoo, d_max, m_pad) if use_slab else zoo
+            return pack_slab_params(models.init_splitnn(cfg, feature_dims),
+                                    d_max, m_pad)
 
         params = fresh_params()
         opt = adam_init(params)
@@ -721,22 +685,17 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
                 if sample_weights is not None else np.ones(n, np.float32))
         w_eff = jnp.asarray(w_np)
 
-        if use_slab:
-            slab = pack_slab(partition.client_features, m_pad)
-            if prog.d_eff > d_max:
-                # align the slab's d to the kernel lane width ONCE, here,
-                # so the per-step gather-fused pass hands the loop-invariant
-                # slab straight to the kernel instead of re-padding it every
-                # scan step (pad_bottom_blocks_gather no-ops on aligned f32;
-                # zero columns meet zero weight rows, values unchanged)
-                slab = np.concatenate(
-                    [slab, np.zeros(slab.shape[:2] + (prog.d_eff - d_max,),
-                                    np.float32)], axis=2)
-            data: Tuple = (jnp.asarray(slab),)
-        else:
-            data = tuple(jnp.asarray(f, jnp.float32)
-                         for f in partition.client_features)
-        arrays = data + (y_all, w_eff)
+        slab = pack_slab(partition.client_features, m_pad)
+        if prog.d_eff > d_max:
+            # align the slab's d to the kernel lane width ONCE, here,
+            # so the per-step gather-fused pass hands the loop-invariant
+            # slab straight to the kernel instead of re-padding it every
+            # scan step (pad_bottom_blocks_gather no-ops on aligned f32;
+            # zero columns meet zero weight rows, values unchanged)
+            slab = np.concatenate(
+                [slab, np.zeros(slab.shape[:2] + (prog.d_eff - d_max,),
+                                np.float32)], axis=2)
+        arrays = (jnp.asarray(slab), y_all, w_eff)
 
         bs = min(cfg.batch_size, n)
         steps_per_epoch = -(-n // bs)
@@ -756,9 +715,9 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
         params, opt = prog.pin_carry(params, opt)
         setup_sp.set(bottom_path=bottom_path(bottom_impl, fuse_gather,
                                              n, prog.d_eff, quant))
-    with span("train.compile", engine="scan", bottom_impl=bottom_impl,
+    with span("train.compile", bottom_impl=bottom_impl,
               steps_per_epoch=steps_per_epoch, padded_batch=padded_bs,
-              mesh=(n_data, n_model), fused_gather=use_slab and fuse_gather):
+              mesh=(n_data, n_model), fused_gather=fuse_gather):
         jax.block_until_ready(jitted(params, opt, idx0, mask0, *arrays))
     params = fresh_params()
     params, opt = prog.pin_carry(params, adam_init(params))
@@ -773,9 +732,9 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
     scale_overhead = scale_bytes_per_step(bs, m, quant)
     per_epoch_bytes = per_sample * n + steps_per_epoch * scale_overhead
     stats = EngineStats(shards=n_data, steps_per_epoch=steps_per_epoch,
-                        padded_batch=padded_bs, engine="scan",
+                        padded_batch=padded_bs,
                         bottom_impl=bottom_impl, model_shards=n_model,
-                        fused_gather=use_slab and fuse_gather,
+                        fused_gather=fuse_gather,
                         quant=quant or "none",
                         gather_payload_bytes=payload_bytes(
                             width, bs, m, quant))
@@ -790,8 +749,8 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
         # the epoch span brackets the ONE dispatch + ONE host sync; it
         # reads the host clock only, so the engine's dispatch/sync
         # contract is identical traced or not (tests/test_obs.py)
-        with span("train.epoch", epoch=epoch, engine="scan",
-                  steps=steps_per_epoch, comm_bytes=per_epoch_bytes) as sp:
+        with span("train.epoch", epoch=epoch, steps=steps_per_epoch,
+                  comm_bytes=per_epoch_bytes) as sp:
             params, opt, ep_loss = jitted(params, opt, idx, mask, *arrays)
             stats.dispatches += 1
             losses.append(float(ep_loss))  # the single host sync this epoch
@@ -807,35 +766,14 @@ def train_scan(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
                 break
     train_seconds = time.perf_counter() - t0
     sim_comm = comm_bytes / bandwidth + latency * 2 * total_steps * m
-    out_params = (unpack_slab_params(params, feature_dims) if use_slab
-                  else params)
     return TrainReport(losses=losses, epochs=epoch, steps=total_steps,
                        train_seconds=train_seconds, comm_bytes=comm_bytes,
-                       simulated_comm_seconds=sim_comm, params=out_params,
+                       simulated_comm_seconds=sim_comm,
+                       params=unpack_slab_params(params, feature_dims),
                        engine_stats=stats)
 
 
-# ----------------------------------------------------------- legacy loop
-
-
-@functools.lru_cache(maxsize=8)
-def _loop_step_fn(cfg):
-    """One jitted legacy-loop step per config, hoisted out of
-    ``train_loop`` so repeated ``engine="loop"`` runs hit the compile
-    cache instead of rebuilding a fresh ``@jax.jit`` wrapper per call
-    (the call-time-jit hazard the lint rule bans).  The data arrays ride
-    in as arguments rather than closures for the same reason: a closure
-    over ``xs_all`` would key the compile cache on array identity."""
-    def step(params, opt, idx, y_all, w_all, *xs_all):
-        from repro.core import splitnn as models
-        xs = [x[idx] for x in xs_all]
-        y = y_all[idx]
-        w = w_all[idx] if w_all is not None else None
-        loss, grads = jax.value_and_grad(
-            lambda p: models._loss_fn(p, cfg, xs, y, w))(params)
-        params, opt = adam_update(params, grads, opt, lr=cfg.lr)
-        return params, opt, loss
-    return jax.jit(step)
+# ----------------------------------------------------------- caches
 
 
 def clear_program_caches() -> None:
@@ -849,77 +787,4 @@ def clear_program_caches() -> None:
     _score_step_fn.cache_clear()
     _score_blocks_fn.cache_clear()
     make_epoch_fn.cache_clear()
-    _loop_step_fn.cache_clear()
     clear_fit_cache()
-
-
-def train_loop(partition, cfg, *, sample_weights: Optional[np.ndarray] = None,
-               bandwidth: float = 10e9 / 8, latency: float = 2e-4,
-               verbose: bool = False) -> TrainReport:
-    """Legacy host epoch loop: one jit dispatch + one blocking sync per
-    minibatch.  Kept as the scan engine's parity oracle and the
-    dispatch-overhead baseline for ``table2_e2e``.  The historical
-    remainder-batch drop (``range(0, n - bs + 1, bs)``) is fixed: the
-    last ``n mod bs`` rows now train as a short batch, and
-    ``comm_bytes`` counts the rows actually shipped."""
-    from repro.core import splitnn as models
-
-    n = partition.n_samples
-    m = partition.n_clients
-    feature_dims = [f.shape[1] for f in partition.client_features]
-    params = models.init_splitnn(cfg, feature_dims)
-    opt = adam_init(params)
-
-    y_np = partition.labels
-    y_all = jnp.asarray(y_np, jnp.float32 if cfg.n_classes == 0
-                        else jnp.int32)
-    xs_all = [jnp.asarray(f, jnp.float32) for f in partition.client_features]
-    w_all = (jnp.asarray(sample_weights, jnp.float32)
-             if sample_weights is not None else None)
-
-    step_fn = _loop_step_fn(cfg)
-
-    def step(params, opt, idx):
-        return step_fn(params, opt, idx, y_all, w_all, *xs_all)
-
-    rng = np.random.default_rng(cfg.seed)
-    bs = min(cfg.batch_size, n)
-    # the legacy loop always communicates f32 activations (no quant
-    # knob); per_sample still derives from the wire dtype (quant=None)
-    per_sample = models.activation_bytes_per_sample(cfg, m, None)
-    stats = EngineStats(shards=1, steps_per_epoch=-(-n // bs),
-                        padded_batch=bs, engine="loop", bottom_impl="loop",
-                        gather_payload_bytes=payload_bytes(
-                            models.activation_width(cfg), bs, m, None))
-    losses: List[float] = []
-    comm_bytes = 0
-    total_steps = 0
-    t0 = time.perf_counter()
-    epoch = 0
-    for epoch in range(1, cfg.max_epochs + 1):
-        order = rng.permutation(n)
-        ep_loss, nb = 0.0, 0
-        with span("train.epoch", epoch=epoch, engine="loop") as sp:
-            for s in range(0, n, bs):
-                idx = jnp.asarray(order[s:s + bs])
-                params, opt, loss = step(params, opt, idx)
-                stats.dispatches += 1
-                ep_loss += float(loss)          # blocking sync EVERY step
-                stats.host_syncs += 1
-                nb += 1
-                total_steps += 1
-                comm_bytes += per_sample * int(idx.shape[0])
-            sp.set(steps=nb)
-        losses.append(ep_loss / max(nb, 1))
-        if verbose and epoch % 10 == 0:
-            print(f"  epoch {epoch}: loss {losses[-1]:.5f}")
-        wlen = cfg.convergence_window
-        if len(losses) > wlen:
-            if abs(losses[-1 - wlen] - losses[-1]) < cfg.convergence_eps:
-                break
-    train_seconds = time.perf_counter() - t0
-    sim_comm = comm_bytes / bandwidth + latency * 2 * total_steps * m
-    return TrainReport(losses=losses, epochs=epoch, steps=total_steps,
-                       train_seconds=train_seconds, comm_bytes=comm_bytes,
-                       simulated_comm_seconds=sim_comm, params=params,
-                       engine_stats=stats)

@@ -185,12 +185,12 @@ def test_epoch_program_one_all_gather_per_step(mesh_name, want_ag):
 
 def test_epoch_program_cache_bounded_and_clearable():
     from repro.sharding import resolve_train_mesh
-    from repro.train.vfl import (_loop_step_fn, _score_step_fn,
+    from repro.train.vfl import (_score_blocks_fn, _score_step_fn,
                                  clear_program_caches, make_epoch_fn)
 
     assert make_epoch_fn.cache_info().maxsize == 16
     assert _score_step_fn.cache_info().maxsize == 32
-    assert _loop_step_fn.cache_info().maxsize == 8
+    assert _score_blocks_fn.cache_info().maxsize == 32
 
     mesh, data_axis, n_data, model_axis, n_model = resolve_train_mesh(None)
     cfg = SplitNNConfig("lr", 2, batch_size=64)

@@ -1,59 +1,12 @@
-"""Unit tests for the static-analysis toolbox: the HLO cost/breakdown
-CLIs (golden fixtures + exit codes), roofline math, the AST lint rules,
-and the BlockSpec VMEM estimators.  Everything here is jax-free except
-the estimators' padding import — no tracing, no devices."""
+"""Unit tests for the static-analysis toolbox: the HLO collective
+parsers, the AST lint rules, and the BlockSpec VMEM estimators.
+Everything here is jax-free except the estimators' padding import — no
+tracing, no devices."""
 import json
 
-import pytest
-
 from repro.analysis import hlo as hlo_mod
-from repro.analysis import breakdown, hlo_cost
-from repro.analysis.roofline import HW, roofline_terms
 
 # ------------------------------------------------------------- fixtures
-
-# minimal optimized-HLO dump: one dot. flops = 2·|out|·K = 2·(8·32)·16
-# = 8192; bytes = out 1024 + operands 512 + 2048 = 3584.
-DOT_HLO = """\
-HloModule m
-
-ENTRY %main (Arg_0.1: f32[8,16], Arg_1.2: f32[16,32]) -> f32[8,32] {
-  %Arg_0.1 = f32[8,16]{1,0} parameter(0)
-  %Arg_1.2 = f32[16,32]{1,0} parameter(1)
-  ROOT %dot.3 = f32[8,32]{1,0} dot(f32[8,16]{1,0} %Arg_0.1, f32[16,32]{1,0} %Arg_1.2), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-}
-"""
-
-# while loop with a static trip count: body flops (1 + 64) and cond
-# flops (1) must be multiplied by known_trip_count=10 → 660 total.
-WHILE_HLO = """\
-HloModule m2
-
-%body (p: (s32[], f32[64])) -> (s32[], f32[64]) {
-  %p = (s32[], f32[64]) parameter(0)
-  %i = s32[] get-tuple-element((s32[], f32[64]) %p), index=0
-  %x = f32[64]{0} get-tuple-element((s32[], f32[64]) %p), index=1
-  %one = s32[] constant(1)
-  %ni = s32[] add(s32[] %i, s32[] %one)
-  %nx = f32[64]{0} add(f32[64]{0} %x, f32[64]{0} %x)
-  ROOT %t = (s32[], f32[64]) tuple(s32[] %ni, f32[64]{0} %nx)
-}
-
-%cond (p.1: (s32[], f32[64])) -> pred[] {
-  %p.1 = (s32[], f32[64]) parameter(0)
-  %i.1 = s32[] get-tuple-element((s32[], f32[64]) %p.1), index=0
-  %n = s32[] constant(10)
-  ROOT %lt = pred[] compare(s32[] %i.1, s32[] %n), direction=LT
-}
-
-ENTRY %main (a: f32[64]) -> f32[64] {
-  %a = f32[64]{0} parameter(0)
-  %z = s32[] constant(0)
-  %init = (s32[], f32[64]) tuple(s32[] %z, f32[64]{0} %a)
-  %w = (s32[], f32[64]) while((s32[], f32[64]) %init), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"10"}}
-  ROOT %out = f32[64]{0} get-tuple-element((s32[], f32[64]) %w), index=1
-}
-"""
 
 COLLECTIVE_HLO = """\
   %ag = f32[16,128]{1,0} all-gather(f32[2,128]{1,0} %x), dimensions={0}
@@ -65,52 +18,6 @@ STABLEHLO = """\
     %0 = "stablehlo.all_gather"(%arg0) : (tensor<8xf32>) -> tensor<64xf32>
     %1 = "stablehlo.reduce_scatter"(%0) : (tensor<64xf32>) -> tensor<8xf32>
 """
-
-
-# ------------------------------------------------------- hlo_cost golden
-
-
-def test_hlo_cost_dot_golden():
-    cost = hlo_cost.analyze_hlo(DOT_HLO)
-    assert cost["flops"] == pytest.approx(8192.0)
-    assert cost["bytes"] == pytest.approx(3584.0)
-
-
-def test_hlo_cost_while_trip_weighting():
-    cost = hlo_cost.analyze_hlo(WHILE_HLO)
-    assert cost["flops"] == pytest.approx(10 * (1 + 64) + 10 * 1)
-    assert cost["bytes"] == pytest.approx(0.0)  # elementwise fuses away
-
-
-def test_hlo_cost_cli_exit_codes(tmp_path, capsys):
-    good = tmp_path / "dot.txt"
-    good.write_text(DOT_HLO)
-    assert hlo_cost.main([str(good)]) == 0
-    out = capsys.readouterr().out
-    assert "flops 8192" in out and "bytes 3584" in out
-
-    assert hlo_cost.main([str(tmp_path / "missing.txt")]) == 2
-
-    bad = tmp_path / "notes.txt"
-    bad.write_text("not an hlo dump\n")
-    assert hlo_cost.main([str(bad)]) == 1
-
-
-def test_breakdown_cli_and_tables(tmp_path, capsys):
-    good = tmp_path / "dot.txt"
-    good.write_text(DOT_HLO)
-    assert breakdown.main([str(good), "5"]) == 0
-    assert "dot -> f32[8,32]" in capsys.readouterr().out
-
-    by_bytes, by_flops = breakdown.breakdown(DOT_HLO)
-    (key, b), = by_bytes.items()
-    assert key.startswith("dot ->") and b == 3584
-    assert by_flops[key] == pytest.approx(8192.0)
-
-    assert breakdown.main([str(tmp_path / "missing.txt")]) == 2
-    bad = tmp_path / "notes.txt"
-    bad.write_text("not an hlo dump\n")
-    assert breakdown.main([str(bad)]) == 1
 
 
 def test_parse_hlo_collectives_bytes():
@@ -127,23 +34,7 @@ def test_stablehlo_counters():
     assert hlo_mod.count_aliased_args(STABLEHLO) == 2
 
 
-def test_roofline_terms_math():
-    t = roofline_terms(
-        flops_per_device=2 * HW.peak_flops,        # 2 s of compute
-        bytes_per_device=0.5 * HW.hbm_bw,          # 0.5 s of HBM
-        collective_bytes_per_device=0.0,
-        model_flops_global=HW.peak_flops, chips=1)
-    assert t["compute_s"] == pytest.approx(2.0)
-    assert t["memory_s"] == pytest.approx(0.5)
-    assert t["collective_s"] == 0.0
-    assert t["dominant"] == "compute_s"
-    assert t["bound_s"] == pytest.approx(2.0)
-    assert t["useful_compute_ratio"] == pytest.approx(0.5)
-    assert t["compute_fraction_of_bound"] == pytest.approx(1.0)
-
-
 # ------------------------------------------------------------- lint rules
-
 
 def _lint(src):
     from repro.analysis.lint import lint_source

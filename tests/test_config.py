@@ -12,8 +12,8 @@ import numpy as np
 import pytest
 
 from conftest import make_cls_partition
-from repro.config import (ALIGN_ALIASES, ENGINE_ALIASES, AlignOptions,
-                          EngineOptions, _coerce_options)
+from repro.config import (ALIGN_ALIASES, AlignOptions, EngineOptions,
+                          _coerce_options)
 from repro.core import SplitNNConfig, run_pipeline
 from repro.core.mpsi import tree_mpsi
 from repro.core.splitnn import train_splitnn
@@ -55,10 +55,10 @@ def assert_reports_bitwise_equal(a, b):
 
 
 def test_options_frozen_and_hashable():
-    opts = EngineOptions(train_engine="scan", block_b=256)
+    opts = EngineOptions(fuse_gather=False, block_b=256)
     with pytest.raises(dataclasses.FrozenInstanceError):
         opts.block_b = 128
-    assert hash(opts) == hash(EngineOptions(train_engine="scan",
+    assert hash(opts) == hash(EngineOptions(fuse_gather=False,
                                             block_b=256))
     assert hash(AlignOptions()) == hash(AlignOptions())
 
@@ -72,8 +72,8 @@ def test_align_inherits_engine_mesh():
 
 
 def test_alias_tables():
-    assert ENGINE_ALIASES["engine"] == "train_engine"
     assert ALIGN_ALIASES["backend"] == "psi_backend"
+    assert ALIGN_ALIASES["engine_impl"] == "impl"
 
 
 # ------------------------------------------------------- coercion shim
@@ -82,55 +82,55 @@ def test_alias_tables():
 def test_coerce_unknown_kwarg_raises():
     with pytest.raises(TypeError, match="unexpected"):
         _coerce_options("f", {"bogus_knob": 1},
-                        ("options", EngineOptions, None, ENGINE_ALIASES))
+                        ("options", EngineOptions, None, {}))
 
 
 def test_coerce_mixing_object_and_legacy_raises():
     with pytest.raises(TypeError):
         _coerce_options("f", {"block_b": 64},
-                        ("options", EngineOptions, EngineOptions(),
-                         ENGINE_ALIASES))
+                        ("options", EngineOptions, EngineOptions(), {}))
 
 
 def test_coerce_warns_and_builds_equal_object():
     with pytest.warns(DeprecationWarning, match="options"):
         (opts,) = _coerce_options(
-            "f", {"engine": "loop", "block_b": 64},
-            ("options", EngineOptions, None, ENGINE_ALIASES))
-    assert opts == EngineOptions(train_engine="loop", block_b=64)
+            "f", {"fuse_gather": False, "block_b": 64},
+            ("options", EngineOptions, None, {}))
+    assert opts == EngineOptions(fuse_gather=False, block_b=64)
 
 
 def test_coerce_routes_keys_across_specs():
     with pytest.warns(DeprecationWarning):
         eng, align = _coerce_options(
-            "f", {"engine": "scan", "protocol": "oprf"},
-            ("options", EngineOptions, None, ENGINE_ALIASES),
+            "f", {"block_b": 128, "protocol": "oprf"},
+            ("options", EngineOptions, None, {}),
             ("align", AlignOptions, None, ALIGN_ALIASES))
-    assert eng.train_engine == "scan" and align.protocol == "oprf"
+    assert eng.block_b == 128 and align.protocol == "oprf"
 
 
 # --------------------------------------------- bitwise parity: training
 
 
 @pytest.mark.parametrize("model", ["lr", "mlp"])
-@pytest.mark.parametrize("engine", ["scan", "loop"])
-def test_train_splitnn_kwargs_vs_options_bitwise(part, model, engine):
+@pytest.mark.parametrize("fuse", [True, False])
+def test_train_splitnn_kwargs_vs_options_bitwise(part, model, fuse):
     cfg = _cfg(model)
     with pytest.warns(DeprecationWarning):
-        legacy = train_splitnn(part, cfg, engine=engine)
-    new = train_splitnn(part, cfg,
-                        options=EngineOptions(train_engine=engine))
+        legacy = train_splitnn(part, cfg, fuse_gather=fuse)
+    new = train_splitnn(part, cfg, options=EngineOptions(fuse_gather=fuse))
     assert_reports_bitwise_equal(legacy, new)
 
 
-def test_train_splitnn_loop_engine_guards(part):
-    with pytest.raises(ValueError, match="loop"):
+def test_train_splitnn_rejects_unknown_engine_and_bottom(part):
+    """One engine: "scan" is the only ``train_engine`` accepted, and a
+    bottom other than "ref"|"pallas" is refused before any program is
+    built."""
+    assert EngineOptions(train_engine="scan") == EngineOptions()
+    with pytest.raises(ValueError, match="train_engine"):
+        EngineOptions(train_engine="loop")
+    with pytest.raises(ValueError, match="bottom_impl"):
         train_splitnn(part, _cfg("lr"),
-                      options=EngineOptions(train_engine="loop",
-                                            quant="int8"))
-    with pytest.raises(ValueError):
-        train_splitnn(part, _cfg("lr"),
-                      options=EngineOptions(train_engine="nope"))
+                      options=EngineOptions(bottom_impl="nope"))
 
 
 # --------------------------------------------- bitwise parity: pipeline
@@ -142,8 +142,7 @@ def test_run_pipeline_kwargs_vs_options_bitwise(parts):
     with pytest.warns(DeprecationWarning):
         legacy = run_pipeline(tr, te, cfg, variant="treecss",
                               clusters_per_client=6, seed=0,
-                              protocol="rsa", engine="scan",
-                              block_b=256)
+                              protocol="rsa", block_b=256)
     new = run_pipeline(tr, te, cfg, variant="treecss",
                        clusters_per_client=6, seed=0,
                        options=EngineOptions(block_b=256),

@@ -1,11 +1,9 @@
-"""Pallas kernel sweeps: shapes × dtypes vs the pure-jnp ref oracles."""
+"""kmeans_assign kernel sweeps: shapes × dtypes vs the pure-jnp ref oracle."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.kmeans_assign import ops as km_ops, ref as km_ref
-from repro.kernels.ssd_scan import ops as ssd_ops, ref as ssd_ref
 
 RNG = np.random.default_rng(7)
 
@@ -34,76 +32,3 @@ def test_kmeans_assign_dtypes(dtype):
                                     c.astype(jnp.float32))
     a_pal, _ = km_ops.kmeans_assign(p, c)
     assert (np.asarray(a_ref) == np.asarray(a_pal)).mean() > 0.97
-
-
-# ----------------------------------------------------------- flash attention
-
-@pytest.mark.parametrize("b,sq,h,kv,dh,kw", [
-    (2, 256, 4, 2, 64, {}),
-    (1, 384, 4, 4, 64, dict(causal=True)),
-    (1, 256, 8, 2, 128, dict(window=64)),
-    (1, 256, 4, 2, 64, dict(window=64, prefix=16)),
-    (1, 256, 4, 2, 64, dict(logit_cap=50.0)),
-    (2, 200, 4, 2, 48, {}),                      # unaligned S and Dh
-    (1, 512, 2, 1, 64, dict(window=128)),
-    (1, 128, 4, 2, 64, dict(causal=False)),
-    (1, 160, 6, 3, 32, dict(window=32, logit_cap=30.0)),
-])
-def test_flash_attention_matches_ref(b, sq, h, kv, dh, kw):
-    q = jnp.asarray(RNG.normal(size=(b, sq, h, dh)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(b, sq, kv, dh)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(b, sq, kv, dh)), jnp.float32)
-    o_ref = fa_ref.flash_attention(q, k, v, **kw)
-    o_pal = fa_ops.flash_attention(q, k, v, **kw)
-    assert float(jnp.max(jnp.abs(o_ref - o_pal))) < 2e-3
-
-
-def test_flash_attention_bf16():
-    q = jnp.asarray(RNG.normal(size=(1, 128, 4, 64)), jnp.bfloat16)
-    k = jnp.asarray(RNG.normal(size=(1, 128, 2, 64)), jnp.bfloat16)
-    v = jnp.asarray(RNG.normal(size=(1, 128, 2, 64)), jnp.bfloat16)
-    o_ref = fa_ref.flash_attention(q, k, v)
-    o_pal = fa_ops.flash_attention(q, k, v)
-    assert o_pal.dtype == jnp.bfloat16
-    err = jnp.max(jnp.abs(o_ref.astype(jnp.float32)
-                          - o_pal.astype(jnp.float32)))
-    assert float(err) < 3e-2
-
-
-# ----------------------------------------------------------------- ssd scan
-
-@pytest.mark.parametrize("b,s,h,p,n,chunk", [
-    (2, 256, 4, 64, 128, 128),
-    (1, 128, 2, 32, 64, 32),
-    (2, 100, 3, 16, 16, 32),     # padded sequence
-    (1, 512, 8, 64, 128, 128),
-    (1, 64, 1, 8, 8, 16),
-])
-def test_ssd_scan_matches_ref(b, s, h, p, n, chunk):
-    x = jnp.asarray(RNG.normal(size=(b, s, h, p)), jnp.float32)
-    dt = jnp.asarray(np.abs(RNG.normal(0.1, 0.05, size=(b, s, h))),
-                     jnp.float32)
-    A = jnp.asarray(-np.abs(RNG.normal(1, 0.3, size=(h,))), jnp.float32)
-    B = jnp.asarray(RNG.normal(size=(b, s, n)), jnp.float32)
-    C = jnp.asarray(RNG.normal(size=(b, s, n)), jnp.float32)
-    y_ref, f_ref = ssd_ref.ssd_scan(x, dt, A, B, C, chunk)
-    y_pal, f_pal = ssd_ops.ssd_scan(x, dt, A, B, C, chunk=chunk)
-    assert float(jnp.max(jnp.abs(y_ref - y_pal))) < 1e-3
-    assert float(jnp.max(jnp.abs(f_ref - f_pal))) < 1e-3
-
-
-def test_ssd_scan_state_continuity():
-    """Scanning [first half] then [second half] with carried state must
-    equal one full scan — validates the VMEM-carried recurrence."""
-    b, s, h, p, n, chunk = 1, 128, 2, 16, 32, 32
-    x = jnp.asarray(RNG.normal(size=(b, s, h, p)), jnp.float32)
-    dt = jnp.asarray(np.abs(RNG.normal(0.1, 0.02, size=(b, s, h))),
-                     jnp.float32)
-    A = jnp.asarray([-0.5, -1.0], jnp.float32)
-    B = jnp.asarray(RNG.normal(size=(b, s, n)), jnp.float32)
-    C = jnp.asarray(RNG.normal(size=(b, s, n)), jnp.float32)
-    y_full, f_full = ssd_ops.ssd_scan(x, dt, A, B, C, chunk=chunk)
-    # reference: recompute second half with the first half's final state
-    # via the oracle's decomposition
-    y_ref, f_ref = ssd_ref.ssd_scan(x, dt, A, B, C, chunk)
-    assert float(jnp.max(jnp.abs(f_full - f_ref))) < 1e-4

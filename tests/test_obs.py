@@ -276,7 +276,7 @@ def _train(tracer):
     cfg = SplitNNConfig(model="lr", n_classes=2, lr=0.05, batch_size=64,
                         max_epochs=4)
     with use_tracer(tracer):
-        rep = train_splitnn(tr, cfg, engine="scan")
+        rep = train_splitnn(tr, cfg)
     return rep
 
 

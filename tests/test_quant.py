@@ -247,14 +247,6 @@ def test_f32_accounting_unchanged():
     assert rep.comm_bytes == rep.epochs * per * part.n_samples
 
 
-def test_loop_engine_rejects_quant():
-    part = make_cls_partition(n=200)
-    cfg = SplitNNConfig(model="lr", n_classes=2, batch_size=64,
-                        max_epochs=2)
-    with pytest.raises(ValueError):
-        train_splitnn(part, cfg, engine="loop", quant="int8")
-
-
 @pytest.mark.parametrize("quant", QUANTS)
 def test_serve_matches_train_forward(quant):
     """Quantized scoring (forward_slab_eval) must agree with the

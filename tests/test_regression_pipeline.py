@@ -196,7 +196,7 @@ def test_coreset_bin_and_group_spans_nest_in_select():
 
 @pytest.mark.parametrize("impl,fuse,path", [
     ("pallas", True, "gather_fused"), ("pallas", False, "dense"),
-    ("ref", True, "ref"), ("loop", True, "loop")])
+    ("ref", True, "ref")])
 def test_train_setup_names_the_bottom_path(impl, fuse, path):
     part = reg_partition(n=100)
     cfg = SplitNNConfig(model="linreg", n_classes=0, max_epochs=1)

@@ -257,19 +257,6 @@ def test_train_2d_gather_fused_bitwise(mesh2d):
 
 
 @needs_8_devices
-def test_train_2d_requires_slab_path(mesh2d):
-    """bottom_impl='loop' keeps ragged per-client params — it cannot map
-    onto the model axis and must raise, not silently run unsharded."""
-    from repro.core.splitnn import SplitNNConfig as Cfg, train_splitnn
-
-    tr = make_cls_partition(n=128, d=9, seed=1)
-    cfg = Cfg(model="lr", n_classes=2, lr=0.05, batch_size=64,
-              max_epochs=2)
-    with pytest.raises(ValueError, match="model-axis"):
-        train_splitnn(tr, cfg, mesh=mesh2d, bottom_impl="loop")
-
-
-@needs_8_devices
 def test_pipeline_2d_mesh_end_to_end(mesh2d):
     """One 2-D mesh knob through run_pipeline: PSI/CSS shard over data
     (byte-identical, model axis replicated), training shards over both

@@ -1,23 +1,13 @@
-"""Data pipeline, checkpointing, sharding rules, HLO analysis."""
-import os
-import tempfile
-
-import jax
-import jax.numpy as jnp
+"""Synthetic data, vertical partitioning, HLO collective parsing."""
 import numpy as np
-import pytest
 try:
     from hypothesis import given, settings, strategies as st
 except ImportError:  # container image has no hypothesis
     from _propcheck import given, settings, strategies as st
-from jax.sharding import PartitionSpec as P
 
 from repro.analysis.hlo import parse_hlo_collectives
-from repro.analysis.hlo_cost import analyze_hlo
-from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.data.synthetic import DATASETS, make_dataset, make_id_universe
 from repro.data.vertical import partition_features
-from repro.sharding import check_divisible, filter_spec, spec_for_param
 
 
 # ------------------------------------------------------------------- data
@@ -66,66 +56,7 @@ def test_vertical_partition_covers_features():
     assert max(sizes) - min(sizes) <= 1   # equal split
 
 
-# -------------------------------------------------------------- checkpoint
-
-def test_checkpoint_roundtrip():
-    tree = {"a": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
-            "b": {"c": jnp.ones((4,), jnp.bfloat16)},
-            "d": [jnp.zeros((2,)), jnp.asarray(3)]}
-    with tempfile.TemporaryDirectory() as td:
-        path = os.path.join(td, "ck.npz")
-        save_checkpoint(path, tree, step=7)
-        restored, meta = load_checkpoint(path, tree)
-        assert meta["step"] == 7
-        for a, b in zip(jax.tree_util.tree_leaves(tree),
-                        jax.tree_util.tree_leaves(restored)):
-            np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                          np.asarray(b, np.float32))
-
-
-# ---------------------------------------------------------------- sharding
-
-def test_param_rules():
-    assert spec_for_param("embed", 2) == P("model", "data")
-    assert spec_for_param("layers/attn/wq", 4) == P(None, "data", "model",
-                                                    None)
-    assert spec_for_param("layers/moe/wi_gate", 4) == P(None, "model",
-                                                        "data", None)
-    assert spec_for_param("final_norm/scale", 1) == P(None)
-
-
-def test_check_divisible_drops_bad_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    # trivially divisible on 1x1
-    assert check_divisible(P("data", "model"), (7, 13), mesh) == P("data",
-                                                                   "model")
-
-
-def test_filter_spec_removes_missing_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    spec = filter_spec(P(("pod", "data"), "model"), mesh)
-    assert spec == P(("data",), "model")
-
-
 # ------------------------------------------------------------ HLO analysis
-
-def test_hlo_flop_counting_matmul_and_scan():
-    co = jax.jit(lambda a, b: a @ b).lower(
-        jax.ShapeDtypeStruct((128, 256), jnp.float32),
-        jax.ShapeDtypeStruct((256, 64), jnp.float32)).compile()
-    r = analyze_hlo(co.as_text())
-    assert r["flops"] == pytest.approx(2 * 128 * 256 * 64, rel=0.05)
-
-    def scanned(x, w):
-        def body(c, _):
-            return c @ w, None
-        return jax.lax.scan(body, x, None, length=7)[0]
-    co2 = jax.jit(scanned).lower(
-        jax.ShapeDtypeStruct((64, 64), jnp.float32),
-        jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
-    r2 = analyze_hlo(co2.as_text())
-    assert r2["flops"] == pytest.approx(7 * 2 * 64 ** 3, rel=0.1)
-
 
 def test_collective_parser():
     hlo = """

@@ -1,10 +1,12 @@
 """Scan-based VFL train engine (repro.train.vfl, DESIGN.md §7):
-parity with the legacy per-step loop, the one-host-sync-per-epoch
+parity with a plain per-step reference, the one-host-sync-per-epoch
 contract, remainder-batch training, and weight semantics."""
 import numpy as np
 import pytest
 
+from _vfl_reference import train_reference
 from conftest import make_cls_partition
+from repro.config import EngineOptions
 from repro.core.splitnn import (SplitNNConfig, activation_bytes_per_sample,
                                 evaluate, train_splitnn)
 
@@ -15,64 +17,82 @@ def _flat(params):
                            for x in jax.tree_util.tree_leaves(params)])
 
 
-# ------------------------------------------------------------------ parity
+# ---------------------------------------------- parity with the reference
 
-def test_scan_matches_legacy_loop():
-    """Same permutation schedule + same per-batch math (bottom_impl=
-    "loop") ⇒ the scan engine reproduces the legacy loop to within
-    reduction-reassociation ulps.  The only float difference is the
-    remainder batch (n=230, bs=64 leaves 38 rows): the scan path sums
-    the weighted loss over 64 pad-masked rows where the loop sums over
-    38 — zero terms are exact, but the reduction tree regroups."""
-    tr = make_cls_partition(n=230, d=12, seed=0)
-    cfg = SplitNNConfig(model="lr", n_classes=2, lr=0.05, batch_size=64,
-                        max_epochs=6)
-    loop = train_splitnn(tr, cfg, engine="loop")
-    scan = train_splitnn(tr, cfg, engine="scan", bottom_impl="loop")
-    assert np.allclose(loop.losses, scan.losses, rtol=1e-6, atol=1e-7)
-    assert np.allclose(_flat(loop.params), _flat(scan.params),
-                       rtol=1e-5, atol=1e-6)
-    assert loop.steps == scan.steps
-    assert loop.comm_bytes == scan.comm_bytes
-    # full batches see IDENTICAL per-step math: with n divisible by bs
-    # the trained params are bitwise-equal (the reported epoch losses
-    # still differ in ulps — host-f64 vs on-device-f32 accumulation)
-    tr64 = make_cls_partition(n=192, d=12, seed=0)
-    cfg64 = SplitNNConfig(model="lr", n_classes=2, lr=0.05, batch_size=64,
-                          max_epochs=4)
-    loop64 = train_splitnn(tr64, cfg64, engine="loop")
-    scan64 = train_splitnn(tr64, cfg64, engine="scan", bottom_impl="loop")
-    assert np.allclose(loop64.losses, scan64.losses, rtol=1e-6, atol=1e-7)
-    assert np.array_equal(_flat(loop64.params), _flat(scan64.params))
+MODELS = {"lr2": ("lr", 2), "lr3": ("lr", 3), "mlp4": ("mlp", 4),
+          "linreg": ("linreg", 0)}
+BOTTOMS = {"ref": ("ref", True), "pallas_fused": ("pallas", True),
+           "pallas_dense": ("pallas", False)}
+# HI's 11/11/10, YP's 30/30/30, one party, and ragged widths whose
+# d_max padding pack_slab has to get right
+WIDTHS = {"hi": (11, 11, 10), "yp": (30, 30, 30), "one": (1,),
+          "ragged": (3, 5, 2, 9, 4)}
 
 
-@pytest.mark.parametrize("bottom_impl", ["ref", "pallas"])
-@pytest.mark.parametrize("model,n_classes", [("lr", 2), ("mlp", 4)])
-def test_scan_slab_matches_loop(model, n_classes, bottom_impl):
-    """The fused block-diagonal slab path (ref oracle / pallas kernel)
-    against the legacy loop: zero-padding is exact, so only GEMM
-    reassociation ulps separate them."""
-    tr = make_cls_partition(n=230, d=11, classes=n_classes, seed=1)
-    te = make_cls_partition(n=150, d=11, classes=n_classes, seed=1)
-    cfg = SplitNNConfig(model=model, n_classes=n_classes, lr=0.02,
-                        batch_size=64, max_epochs=6)
-    loop = train_splitnn(tr, cfg, engine="loop")
-    scan = train_splitnn(tr, cfg, engine="scan", bottom_impl=bottom_impl)
-    assert np.allclose(loop.losses, scan.losses, rtol=1e-4, atol=1e-6)
-    assert abs(evaluate(loop.params, cfg, te)
-               - evaluate(scan.params, cfg, te)) <= 0.02
-
-
-def test_linreg_scan_matches_loop():
+def _parties(widths, n_classes, n, seed):
+    """Rows of one gaussian-mixture table split into parties of the given
+    widths."""
     from repro.data.synthetic import DatasetSpec, make_dataset
-    from repro.data.vertical import partition_features
-    x, y = make_dataset(DatasetSpec("r", 300, 10, 0), seed=2)
-    tr = partition_features(x, y, 3)
-    cfg = SplitNNConfig(model="linreg", n_classes=0, lr=0.05, batch_size=64,
-                        max_epochs=5)
-    loop = train_splitnn(tr, cfg, engine="loop")
-    scan = train_splitnn(tr, cfg)
-    assert np.allclose(loop.losses, scan.losses, rtol=1e-4, atol=1e-6)
+    from repro.data.vertical import VerticalPartition
+
+    x, y = make_dataset(DatasetSpec("t", n, sum(widths), n_classes),
+                        seed=seed)
+    cols = np.cumsum((0,) + widths)
+    slices = [slice(int(a), int(b)) for a, b in zip(cols[:-1], cols[1:])]
+    return VerticalPartition([x[:, sl].copy() for sl in slices], y, slices)
+
+
+def _coreset_weights(n, seed):
+    """Non-uniform weights with about a fifth of them zero, as a coreset
+    gives (zero for rows no centroid kept)."""
+    rng = np.random.default_rng(seed)
+    w = rng.uniform(0.2, 4.0, n).astype(np.float32)
+    w[rng.random(n) < 0.2] = 0.0
+    return w
+
+
+def _check_against_reference(widths, model, n_classes, bottom_impl, fuse,
+                             n, weighted):
+    tr = _parties(widths, n_classes, n, seed=1)
+    te = _parties(widths, n_classes, 150, seed=2)
+    cfg = SplitNNConfig(model=model, n_classes=n_classes, lr=0.02,
+                        batch_size=64, max_epochs=5)
+    w = _coreset_weights(n, seed=3) if weighted else None
+    rep = train_splitnn(tr, cfg, sample_weights=w,
+                        options=EngineOptions(bottom_impl=bottom_impl,
+                                              fuse_gather=fuse))
+    ref = train_reference(tr, cfg, sample_weights=w)
+    assert np.allclose(rep.losses, ref.losses, rtol=1e-4, atol=1e-6)
+    assert (rep.epochs, rep.steps, rep.comm_bytes) == (
+        ref.epochs, ref.steps, ref.comm_bytes)
+    assert abs(evaluate(rep.params, cfg, te)
+               - evaluate(ref.params, cfg, te)) <= 0.02
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unweighted", "weighted"])
+@pytest.mark.parametrize("widths", list(WIDTHS.values()), ids=list(WIDTHS))
+@pytest.mark.parametrize("bottom", list(BOTTOMS), ids=list(BOTTOMS))
+@pytest.mark.parametrize("model", list(MODELS), ids=list(MODELS))
+def test_scan_matches_reference(model, bottom, widths, weighted):
+    """The one engine against the plain per-step f32 reference
+    (``tests/_vfl_reference.py``) on the same schedule, over the axes
+    the engine's packing, padding and fusion depend on: the model head,
+    the bottom path, the party widths and the sample weights.  230 rows
+    at batch 64 leave a 38-row remainder the engine pads and masks;
+    zero padding is exact, so only GEMM reassociation separates them."""
+    _check_against_reference(widths, *MODELS[model], *BOTTOMS[bottom],
+                             n=230, weighted=weighted)
+
+
+@pytest.mark.parametrize("model", ["lr2", "linreg"])
+@pytest.mark.parametrize("bottom", ["ref", "pallas_fused"])
+@pytest.mark.parametrize("n", [40, 64, 193])
+def test_scan_matches_reference_rows(n, bottom, model):
+    """Row counts at the batch's edges: fewer rows than a batch, exactly
+    one batch, and a remainder of one row."""
+    _check_against_reference((11, 11, 10), *MODELS[model], *BOTTOMS[bottom],
+                             n=n, weighted=False)
 
 
 @pytest.mark.parametrize("bottom_impl", ["ref", "pallas"])
@@ -109,34 +129,28 @@ def test_fuse_gather_mlp_bitwise():
 # ------------------------------------------------------- dispatch contract
 
 def test_scan_one_dispatch_and_sync_per_epoch():
-    """The engine's measured counts: the scan path dispatches and syncs
-    exactly once per epoch; the legacy loop pays both once per STEP."""
+    """The engine's measured counts: it dispatches and syncs exactly once
+    per epoch, however many steps the epoch takes."""
     tr = make_cls_partition(n=300, d=9, seed=2)
     cfg = SplitNNConfig(model="lr", n_classes=2, lr=0.05, batch_size=64,
                         max_epochs=7)
     scan = train_splitnn(tr, cfg)
     st = scan.engine_stats
-    assert st.engine == "scan"
     assert st.dispatches == scan.epochs
     assert st.host_syncs == scan.epochs
-    loop = train_splitnn(tr, cfg, engine="loop")
-    lt = loop.engine_stats
-    assert lt.dispatches == loop.steps
-    assert lt.host_syncs == loop.steps
-    assert loop.steps > loop.epochs          # the contrast being claimed
+    assert scan.steps > scan.epochs          # the contrast being claimed
 
 
 # -------------------------------------------------------- remainder batch
 
-@pytest.mark.parametrize("engine", ["scan", "loop"])
-def test_remainder_rows_trained(engine):
+def test_remainder_rows_trained():
     """n=70, bs=64: the seed loop (range(0, n-bs+1, bs)) trained 64 of 70
-    rows per epoch.  Both engines must now train all n rows and count
-    the actual rows in comm_bytes."""
+    rows per epoch.  The engine must train all n rows and count the
+    actual rows in comm_bytes."""
     tr = make_cls_partition(n=70, d=8, seed=3)
     cfg = SplitNNConfig(model="lr", n_classes=2, lr=0.05, batch_size=64,
                         max_epochs=4)
-    rep = train_splitnn(tr, cfg, engine=engine)
+    rep = train_splitnn(tr, cfg)
     per = activation_bytes_per_sample(cfg, tr.n_clients)
     assert rep.steps == rep.epochs * 2       # 64-row + 6-row batches
     assert rep.comm_bytes == rep.epochs * 70 * per
@@ -169,12 +183,6 @@ def test_sample_weights_none_equals_ones():
                            sample_weights=np.ones(tr.n_samples, np.float32))
     assert np.array_equal(_flat(r_none.params), _flat(r_ones.params))
     assert r_none.losses == r_ones.losses
-    # legacy loop takes a different code path for None (w=None inside
-    # the jit'd loss) — same math, ulps-tight
-    l_none = train_splitnn(tr, cfg, engine="loop", sample_weights=None)
-    l_ones = train_splitnn(tr, cfg, engine="loop",
-                           sample_weights=np.ones(tr.n_samples, np.float32))
-    assert np.allclose(l_none.losses, l_ones.losses, rtol=1e-6, atol=1e-9)
 
 
 def test_scan_convergence_criterion_stops_early():
